@@ -14,8 +14,11 @@ a seed batch and reports
     partition's boundary mass, the locality bound), and their ratio.
 
 Because the main benchmark process runs single-device, the measurement runs
-in a subprocess with ``--xla_force_host_platform_device_count=8`` (the same
-recipe as tests/test_distributed.py), tiny enough for the CI smoke gate.
+in a subprocess on 8 virtual CPU devices
+(``JAX_PLATFORMS=cpu``, ``--xla_force_host_platform_device_count=8``; the
+same recipe as tests/test_distributed.py), tiny enough for the CI smoke
+gate.  The child is pinned to the CPU because the parent may hold the
+chip; its rows say ``platform=cpu``.
 Emits the usual CSV rows; the returned dict lands in
 ``BENCH_dist_batched.json``.
 """
@@ -32,6 +35,7 @@ _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, time
+import jax
 import numpy as np
 from repro.launch.mesh import make_host_mesh
 from repro.graphs import sbm, rand_local, GraphHandle
@@ -64,7 +68,7 @@ wall_us = (time.perf_counter() - t0) * 1e6
 rounds = int(out.iterations.sum())
 exchanged = int(out.exchanged.sum())
 res = dict(
-    graph=cfg["graph"], n=g.n, m=g.m, num_shards=pg.num_shards, B=cfg["B"],
+    platform=jax.devices()[0].platform, graph=cfg["graph"], n=g.n, m=g.m, num_shards=pg.num_shards, B=cfg["B"],
     wall_us=wall_us, rounds_total=rounds, exchange_total=exchanged,
     exchange_per_round=exchanged / max(rounds, 1),
     boundary_edges=boundary,
@@ -92,6 +96,7 @@ def run(smoke: bool = False) -> dict:
     env["PYTHONPATH"] = _src_path() + os.pathsep + env.get("PYTHONPATH", "")
     env["DIST_BENCH_CFG"] = json.dumps(cfg)
     env.pop("XLA_FLAGS", None)   # the child sets its own device count
+    env["JAX_PLATFORMS"] = "cpu"  # never the chip: the parent may hold it
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                           capture_output=True, text=True, timeout=1200)
     if proc.returncode != 0:
@@ -101,7 +106,7 @@ def run(smoke: bool = False) -> dict:
     res = json.loads(line[len("RESULT:"):])
     emit(f"dist_batched/{res['graph']}/B={res['B']}_D={res['num_shards']}",
          res["wall_us"],
-         f"exch_per_round={res['exchange_per_round']:.1f};"
+         f"platform={res['platform']};exch_per_round={res['exchange_per_round']:.1f};"
          f"boundary_edges={res['boundary_edges']};"
          f"exch_over_boundary={res['exchange_over_boundary']:.3f};"
          f"rounds={res['rounds_total']}")

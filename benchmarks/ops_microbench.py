@@ -1,4 +1,4 @@
-"""Op-layer micro-benchmarks: the four hot primitives, per backend.
+"""Op-layer micro-benchmarks: the three hot primitives, per backend.
 
 Times each ``repro.core.ops`` op under ``backend="xla"`` and
 ``backend="pallas"`` on representative driver shapes (scatter batches the
@@ -15,8 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ops
-from repro.kernels import ops as kops
-from .common import get_graph, emit, timeit
+from .common import emit, timeit
 
 
 def _interp_tag() -> str:
@@ -75,19 +74,6 @@ def run(smoke: bool = False):
         emit(f"ops/prefix_sum_i32_{backend}", us, f"n={m}{tag}")
     _assert_bitwise(outs["xla"], outs["pallas"], "prefix_sum")
 
-    # diffusion_spmv — saturated round on the hybrid ELL layout (allclose op)
-    g = get_graph("sbm-planted" if smoke else "randLocal-50k")
-    nbr, wgt, es, ed, ew, n_pad, W = kops.pack_banded_ell(g, halo=2)
-    p = jnp.asarray(rng.random(n_pad), jnp.float32)
-    for backend in ("xla", "pallas"):
-        us, outs[backend] = timeit(ops.diffusion_spmv, nbr, wgt, es, ed, ew,
-                                   p, halo=2, backend=backend,
-                                   prime=not smoke)
-        tag = _interp_tag() if backend == "pallas" else ""
-        emit(f"ops/diffusion_spmv_{backend}", us, f"n={n_pad};W={W}{tag}")
-    np.testing.assert_allclose(np.asarray(outs["xla"]),
-                               np.asarray(outs["pallas"]), rtol=1e-5,
-                               atol=1e-6)
     # artifact-level flag, mirrored per-row above: BENCH_ops.json numbers
     # from an interpret-mode host must never be read as TPU numbers
     return dict(default_backend=jax.default_backend(),

@@ -34,11 +34,13 @@ def main() -> None:
                     help="smallest config per benchmark; used by CI")
     ap.add_argument("--only", default=None,
                     help="comma list: table1,table3,fig2,fig6,fig9,fig10,"
-                         "kernels,batched,sparse_batched,ops,serve,"
+                         "batched,sparse_batched,ops,serve,"
                          "dist_batched")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from . import (table1_pushes, table3_runtimes, fig2_opt_rule, fig6_params,
-                   fig9_sweep_scaling, fig10_ncp, kernels_bench, batched_bench,
+                   fig9_sweep_scaling, fig10_ncp, batched_bench,
                    sparse_batched_bench, ops_microbench, serve_bench,
                    dist_batched_bench)
     from .common import drain_rows
@@ -50,7 +52,6 @@ def main() -> None:
         "fig6": lambda: fig6_params.run(smoke=smoke),
         "fig9": lambda: fig9_sweep_scaling.run(smoke=smoke),
         "fig10": lambda: fig10_ncp.run(smoke=smoke),
-        "kernels": lambda: kernels_bench.run(smoke=smoke),
         "batched": lambda: batched_bench.run(smoke=smoke),
         "sparse_batched": lambda: sparse_batched_bench.run(smoke=smoke),
         "ops": lambda: ops_microbench.run(smoke=smoke),

@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import pr_nibble, hk_pr, sweep_cut_dense, batched_pr_nibble
 from repro.graphs import rand_local
 from repro.serve import AsyncClusterEngine, ClusterRequest, LocalClusterEngine
@@ -39,6 +40,7 @@ def main():
                     help="engine lane type; sparse = O(cap_v) state per lane "
                          "(HK-PR requests always serve dense)")
     args = ap.parse_args()
+    use_compile_cache()
 
     print(f"building randLocal graph (n={args.n}) ...")
     g = rand_local(args.n, degree=5, seed=0)

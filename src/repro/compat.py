@@ -1,11 +1,8 @@
-"""Version-compat shims for the sharding APIs the distributed engine uses.
+"""The sharding helpers the distributed engine uses, in one place.
 
-The repo targets the modern spelling (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh`` with ``axis_types``) but must also run on the jax builds
-baked into CPU CI containers, where ``shard_map`` still lives under
-``jax.experimental`` (flag spelled ``check_rep``) and ``AxisType`` does not
-exist yet.  Every shard_map/mesh construction in the repo goes through these
-two helpers so the fallback lives in exactly one place.
+Every shard_map/mesh construction in the repo goes through these two
+helpers, so the flags they set (replication checking off, Auto axis types)
+live in exactly one place.
 """
 from __future__ import annotations
 
@@ -15,19 +12,12 @@ __all__ = ["shard_map", "make_mesh"]
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -143,8 +143,8 @@ def scatter_add_dense(vec: jnp.ndarray, idx: jnp.ndarray, vals: jnp.ndarray,
     """fetchAdd → scatter-add: accumulate ``vals`` at ``idx`` (masked).
 
     Deterministic on every backend (XLA scatter-add has a defined combine
-    order; the Pallas MXU path preserves it — see :mod:`repro.core.ops`),
-    replacing the paper's atomic fetch-and-add.
+    order; the Pallas kernel folds in submission order — see
+    :mod:`repro.core.ops`), replacing the paper's atomic fetch-and-add.
     """
     return ops.scatter_add(vec, idx, vals, valid, backend=backend)
 

@@ -79,7 +79,7 @@ def ncp(graph, num_seeds: int = 256,
     ``ops_backend`` ("xla" | "pallas" | "auto") is orthogonal to the lane
     choice: it selects the kernel backend every scatter/merge/scan inside
     either path dispatches through (:mod:`repro.core.ops`); profiles are
-    bit-identical across ops backends.
+    bit-identical across ops backends where XLA folds in update order.
     """
     if backend not in ("dense", "sparse", "dist"):
         raise ValueError(f"unknown backend: {backend!r}")

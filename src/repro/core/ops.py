@@ -1,6 +1,6 @@
-"""Unified kernel-dispatch layer for the four hot primitives (``core.ops``).
+"""Unified kernel-dispatch layer for the three hot primitives (``core.ops``).
 
-Every hot loop in the drivers bottoms out in one of four primitives — the
+Every hot loop in the drivers bottoms out in one of three primitives — the
 paper's §3 vocabulary, restated as ops:
 
   ============== ====================================== =====================
@@ -8,8 +8,7 @@ paper's §3 vocabulary, restated as ops:
   ============== ====================================== =====================
   scatter_add    atomic fetchAdd (batched)              kernels/scatter_accum
   segment_merge  sparse-set batch insert (sort-merge)   kernels/segment_merge
-  diffusion_spmv saturated push round (A D⁻¹ p)         kernels/ell_spmv
-  prefix_sum     prefix sum (Blelloch scan)             kernels/prefix_scan
+  prefix_sum     prefix sum                             kernels/prefix_scan
   ============== ====================================== =====================
 
 This module is the single seam between the drivers (frontier / sparsevec /
@@ -19,30 +18,31 @@ driver never names a kernel, it names an op and a *backend*.
 Backends
 --------
 ``"xla"``
-    The reference: plain jnp/XLA scatter, sort + ``segment_sum``, gather
-    SpMV, ``jnp.cumsum`` — byte-for-byte the pre-op-layer driver code.
+    The reference: plain jnp/XLA scatter, sort + ``segment_sum``,
+    ``jnp.cumsum`` — byte-for-byte the pre-op-layer driver code.
 ``"pallas"``
-    The MXU kernels (interpret mode off-TPU, so the same code path is
-    exercised in CI on CPU).  Fold orders are preserved (stable sort +
-    in-order one-hot contraction + carried left folds), so ``scatter_add``
-    and ``segment_merge`` are *bit-identical* to ``xla`` in interpret mode,
-    and ``prefix_sum`` is bit-identical for the integer dtypes the drivers
-    scan (associativity is exact in int arithmetic).  ``diffusion_spmv``
-    reassociates the banded row reduction and is allclose, not bit-equal.
+    The Pallas kernels, compiled on a TPU and run in the Pallas interpreter
+    elsewhere (:func:`repro.kernels.ops.interpret` decides).  ``scatter_add``
+    and ``segment_merge`` compute XLA's combine order by construction — a
+    left fold per destination / per run in stream order, with no matmul and
+    no reassociation — and ``prefix_sum`` is exact for the integer dtypes
+    the drivers scan.  So every driver is bit-identical across the two
+    backends wherever XLA's scatter folds in update order, as it does on
+    CPU (``tests/test_ops.py``).  XLA's f32 scatter on a TPU v5e does not,
+    so there the backends can differ in the last bits (``chip_smoke.py``
+    reports it; docs/algorithms.md, guarantee #6).  f32 ``prefix_sum``
+    reassociates.
 ``"auto"``
-    Resolves once at trace time: ``pallas`` on TPU, ``xla`` elsewhere.
+    ``xla`` on every platform: it is the only path whose answers are known
+    on every platform.  ``pallas`` runs only when asked for by name.
 
-Two trace-time guards keep ``pallas`` exact and deployable at the capacity
-ladder's extremes: integer ``scatter_add`` stays on the XLA scatter (an f32
-MXU round-trip is only exact below 2²⁴ and ints gain nothing from the MXU),
-and ``segment_merge`` streams longer than ``_MERGE_PALLAS_MAX_STREAM`` fall
-back to the XLA merge (the fused kernel holds the stream in VMEM).  Both
-fallbacks are bit-identical by the invariant above, so they are pure
-performance decisions.
+The ``pallas`` backend has no size bound and hands no part of an op to XLA:
+the scatter kernel folds every contribution of a destination group itself,
+however many there are (see :func:`repro.kernels.ops.scatter_fold`).  Its
+kernels take 32-bit dtypes.
 
 Extending: :func:`register_backend` installs a new named implementation set
-(e.g. a sharded scatter, an HK-PR sparse-state merge) without touching any
-driver — they all take ``backend=`` and pass it here.
+without touching any driver — they all take ``backend=`` and pass it here.
 """
 from __future__ import annotations
 
@@ -52,13 +52,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
-from repro.kernels.segment_merge import segment_merge_sorted
 
 __all__ = ["OPS", "backends", "register_backend", "resolve",
-           "scatter_add", "segment_merge", "diffusion_spmv", "prefix_sum",
+           "scatter_add", "segment_merge", "prefix_sum",
            "graph_degrees", "graph_expand", "local_csr"]
 
-OPS = ("scatter_add", "segment_merge", "diffusion_spmv", "prefix_sum")
+OPS = ("scatter_add", "segment_merge", "prefix_sum")
 
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 
@@ -81,9 +80,9 @@ def backends() -> tuple:
 
 
 def resolve(backend: str) -> str:
-    """Concrete backend name for ``backend`` ("auto" → TPU? pallas : xla)."""
+    """Concrete backend name for ``backend`` ("auto" → "xla")."""
     if backend is None or backend == "auto":
-        return "pallas" if kops.on_tpu() else "xla"
+        return "xla"
     if backend not in _REGISTRY:
         raise ValueError(
             f"unknown ops backend {backend!r}; registered: {backends()}")
@@ -100,8 +99,9 @@ def scatter_add(vec, idx, vals, valid=None, *, backend: str = "xla"):
     """Masked ``vec.at[idx].add(vals)`` — the batched fetchAdd.
 
     ``valid`` masks both the index (dropped via the shared sentinel
-    ``vec.shape[0]``) and the value; ``None`` means all valid.  Any dtype;
-    the result keeps ``vec``'s dtype.  Backends agree bitwise (see module
+    ``vec.shape[0]``) and the value; ``None`` means all valid.  The result
+    keeps ``vec``'s dtype (any dtype on ``xla``, 32-bit on ``pallas``).
+    Backends agree bitwise where XLA folds in update order (see module
     docstring)."""
     if valid is None:
         valid = jnp.ones(idx.shape, bool)
@@ -118,14 +118,6 @@ def segment_merge(ids, vals, n: int, cap: int, *, backend: str = "xla"):
     overflow as ``count > cap``.  This is the body of
     :func:`repro.core.sparsevec.sv_merge_add`."""
     return _impl("segment_merge", backend)(ids, vals, n, cap)
-
-
-def diffusion_spmv(nbr, wgt, esc_src, esc_dst, esc_w, p, halo: int = 1, *,
-                   backend: str = "xla"):
-    """One saturated diffusion product y = coef·(A D⁻¹)p on the hybrid
-    banded-ELL + escaper-COO layout of :func:`repro.kernels.ops.pack_banded_ell`."""
-    return _impl("diffusion_spmv", backend)(nbr, wgt, esc_src, esc_dst,
-                                            esc_w, p, halo)
 
 
 def prefix_sum(x, *, backend: str = "xla"):
@@ -199,13 +191,6 @@ def _segment_merge_xla(ids, vals, n, cap):
     return out_ids, out_vals, count
 
 
-def _diffusion_spmv_xla(nbr, wgt, esc_src, esc_dst, esc_w, p, halo):
-    n_pad = p.shape[0]
-    safe = jnp.clip(nbr, 0, n_pad - 1)
-    y = jnp.sum(jnp.where(nbr < n_pad, wgt * p[safe], 0.0), axis=1)
-    return y.at[esc_src].add(esc_w * p[esc_dst])
-
-
 def _prefix_sum_xla(x):
     return jnp.cumsum(x)
 
@@ -213,49 +198,22 @@ def _prefix_sum_xla(x):
 register_backend("xla",
                  scatter_add=_scatter_add_xla,
                  segment_merge=_segment_merge_xla,
-                 diffusion_spmv=_diffusion_spmv_xla,
                  prefix_sum=_prefix_sum_xla)
 
 
 # ------------------------------------------------------------------- pallas
 
-_MERGE_PALLAS_MAX_STREAM = 1 << 20  # VMEM bound: the kernel holds the stream
-
-
 def _scatter_add_pallas(vec, idx, vals, valid):
-    if not jnp.issubdtype(vec.dtype, jnp.floating):
-        # integer scatters gain nothing from the MXU and would round-trip
-        # through f32 (exact only below 2^24, which the capacity-ladder
-        # extremes can exceed) — keep them on the always-exact XLA scatter
-        return _scatter_add_xla(vec, idx, vals, valid)
-    cap = vec.shape[0]
-    safe = jnp.where(valid, idx, cap).astype(jnp.int32)
-    masked = jnp.where(valid, vals, 0)
-    out = kops.scatter_fold_via_mxu(vec.astype(jnp.float32), safe,
-                                    masked.astype(jnp.float32))
-    return out.astype(vec.dtype)
+    safe = jnp.where(valid, idx, vec.shape[0])
+    return kops.scatter_fold(vec, safe, jnp.where(valid, vals, 0))
 
 
 def _segment_merge_pallas(ids, vals, n, cap):
-    if ids.shape[0] > _MERGE_PALLAS_MAX_STREAM:
-        # the fused kernel keeps the whole stream in VMEM; ladder-extreme
-        # buckets (cap_e ≳ 2^20) stay on the xla merge (trace-time branch —
-        # shapes are static, so this costs nothing and results are
-        # bit-identical either way)
-        return _segment_merge_xla(ids, vals, n, cap)
     order = jnp.argsort(ids)                 # same stable sort as xla
-    return segment_merge_sorted(ids[order].astype(jnp.int32),
-                                vals[order].astype(jnp.float32), n, cap,
-                                interpret=not kops.on_tpu())
-
-
-def _diffusion_spmv_pallas(nbr, wgt, esc_src, esc_dst, esc_w, p, halo):
-    return kops.diffusion_spmv(nbr, wgt, esc_src, esc_dst, esc_w, p,
-                               halo=halo)
+    return kops.segment_merge_sorted(ids[order], vals[order], n, cap)
 
 
 register_backend("pallas",
                  scatter_add=_scatter_add_pallas,
                  segment_merge=_segment_merge_pallas,
-                 diffusion_spmv=_diffusion_spmv_pallas,
-                 prefix_sum=kops.prefix_sum_exact)
+                 prefix_sum=kops.prefix_sum)

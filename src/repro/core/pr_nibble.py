@@ -102,7 +102,7 @@ def pr_nibble_round(graph: CSRGraph, s: PRNibbleState, eps, alpha,
 
     ``backend`` selects the kernel backend for every scatter/scan in the
     round (see :mod:`repro.core.ops`); results are bit-identical across
-    backends (interpret mode off-TPU)."""
+    backends where XLA folds in update order (guarantee #6)."""
     n = graph.n
     deg = graph.deg
     f = s.frontier

@@ -88,8 +88,8 @@ def pr_nibble_sparse_round(graph: CSRGraph, s: PRNibbleSparseState, eps, alpha,
 
     ``backend`` routes both ``sv_merge_add`` reductions (the round's hot
     loop) plus the expand/pack scans through :mod:`repro.core.ops` —
-    ``"pallas"`` runs them on the fused segment-merge kernel with
-    bit-identical results (interpret mode off-TPU)."""
+    ``"pallas"`` runs them on the segment-merge kernel with bit-identical
+    results where XLA folds in update order (guarantee #6)."""
     n = graph.n
     deg = graph.deg
     f = s.frontier

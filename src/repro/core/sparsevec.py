@@ -17,8 +17,8 @@ the driver retries one bucket up (see frontier.py).
 
 The merge-add reduction itself (sort → sum-duplicates → compact) is an op:
 it dispatches through :func:`repro.core.ops.segment_merge`, so ``backend=
-"pallas"`` fuses the post-sort pipeline into the MXU segment-merge kernel
-(kernels/segment_merge.py) with bit-identical results to the XLA reference.
+"pallas"`` folds each run on the segment-merge kernel
+(kernels/segment_merge.py) in the XLA reference's order.
 """
 from __future__ import annotations
 

@@ -37,10 +37,13 @@ where that padding is made invisible — distributed state vectors of length
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Optional
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .csr import CSRGraph
 from .partition import PartitionedCSR, partition_rows
@@ -61,7 +64,7 @@ class GraphHandle:
         if csr is None and pg is None:
             raise ValueError("GraphHandle needs a CSRGraph or a PartitionedCSR")
         self._csr = csr
-        self._pg = pg
+        self._pg = None if pg is None else _place(pg, mesh, axis)
         self.mesh = mesh
         self.axis = axis
         # Monotonic content version: the serving layer's seed→result cache
@@ -170,7 +173,8 @@ class GraphHandle:
         if self._pg is None:
             if num_shards is None:
                 num_shards = int(self.require_mesh().shape[self.axis])
-            self._pg = partition_rows(self._csr, num_shards)
+            self._pg = _place(partition_rows(self._csr, num_shards),
+                              self.mesh, self.axis)
         elif num_shards is not None and num_shards != self._pg.num_shards:
             raise ValueError(
                 f"handle is partitioned over {self._pg.num_shards} shards, "
@@ -181,6 +185,20 @@ class GraphHandle:
         tag = (f"partitioned[{self.num_shards}x{self._pg.rows_per}]"
                if self.is_sharded else "local")
         return f"GraphHandle({tag}, n={self.n}, m={self.m})"
+
+
+def _place(pg: PartitionedCSR, mesh: Any, axis: str) -> PartitionedCSR:
+    """Put slab ``d`` of every per-shard array on the ``d``-th device of
+    ``mesh``'s ``axis``, so the sharded drivers find their shards in place
+    instead of moving the whole graph from one device on every call.
+    Without a mesh (or with one of another width) the slabs stay where
+    they are."""
+    if mesh is None or int(mesh.shape[axis]) != pg.num_shards:
+        return pg
+    rows = NamedSharding(mesh, PartitionSpec(axis))
+    return dataclasses.replace(pg, indptr=jax.device_put(pg.indptr, rows),
+                               indices=jax.device_put(pg.indices, rows),
+                               deg=jax.device_put(pg.deg, rows))
 
 
 def _gather_csr(pg: PartitionedCSR) -> CSRGraph:

@@ -1,20 +1,17 @@
 """Pallas TPU kernels for the paper's compute hot-spots.
 
-  ell_spmv      — banded ELL SpMV (saturated diffusion round), one-hot MXU gather
-  scatter_accum — sort-bucketed scatter-add (fetchAdd → systolic contraction)
-  prefix_scan   — two-phase blocked prefix sum (sweep-cut backbone)
-  segment_merge — fused sorted-segment merge (sv_merge_add's post-sort pass)
+  scatter_accum — ordered scatter-add (fetchAdd), folded group by group
+  prefix_scan   — blocked prefix sum (sweep-cut backbone)
+  segment_merge — segmented left fold (sv_merge_add's run reduction)
 
-``ops`` holds the jit'd layout wrappers, ``ref`` the pure-jnp oracles.
-Kernels compile for TPU; on CPU they run under ``interpret=True``.  Drivers
-never import these directly — they dispatch through :mod:`repro.core.ops`.
+``ops`` holds the layout wrappers and decides interpret mode, ``ref`` the
+plain oracles.  Kernels compile for TPU; on other platforms they run under
+``interpret=True``.  Drivers never import these directly — they dispatch
+through :mod:`repro.core.ops`.
 """
 from . import ops, ref
-from .ell_spmv import band_spmv, ROW_BLOCK
-from .scatter_accum import scatter_accum_tiles, TILE
-from .prefix_scan import block_scan, BLOCK
-from .segment_merge import segment_merge_sorted, segment_merge_stream, BLK
+from .scatter_accum import scatter_fold_groups
+from .prefix_scan import block_scan
+from .segment_merge import fold_runs
 
-__all__ = ["ops", "ref", "band_spmv", "ROW_BLOCK", "scatter_accum_tiles",
-           "TILE", "block_scan", "BLOCK", "segment_merge_sorted",
-           "segment_merge_stream", "BLK"]
+__all__ = ["ops", "ref", "scatter_fold_groups", "block_scan", "fold_runs"]

@@ -1,187 +1,114 @@
-"""jit'd public wrappers around the Pallas kernels.
+"""Layout wrappers around the Pallas kernels.
 
-These own the layout work (ELL packing, sort-and-bucket, padding) so callers
-deal in graph/CSR terms; on non-TPU backends they flip ``interpret=True``
-automatically (the kernels execute in the Pallas interpreter for parity
-testing — TPU is the compile target).
+These own the layout work (sort-and-group, padding, run flags, compaction)
+so callers deal in vector and stream terms.  :func:`interpret` is the one
+place that decides whether the kernels run compiled or in the Pallas
+interpreter: compiled on a TPU, interpreted everywhere else (the kernels'
+parity tests run on CPU that way).
 """
 from __future__ import annotations
 
-import functools
-
-import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .ell_spmv import band_spmv, ROW_BLOCK
-from .scatter_accum import scatter_accum_tiles, TILE
-from .prefix_scan import block_scan, BLOCK
+from . import prefix_scan, scatter_accum, segment_merge
 
-__all__ = ["on_tpu", "diffusion_spmv", "scatter_add_via_mxu",
-           "scatter_fold_via_mxu", "prefix_sum", "prefix_sum_exact",
-           "pack_banded_ell"]
+__all__ = ["on_tpu", "interpret", "scatter_fold", "prefix_sum",
+           "segment_merge_sorted"]
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _interp() -> bool:
+def interpret() -> bool:
+    """Run the kernels in the Pallas interpreter?  Never on a TPU."""
     return not on_tpu()
 
 
-def pack_banded_ell(graph, halo: int = 1, coef: float = 0.5):
-    """Split a CSR graph into (banded-ELL part, escaper COO part).
-
-    Band-resident edges (|block(src) − block(dst)| ≤ halo) go to the ELL
-    table consumed by the kernel; the rest go to a COO list handled by an
-    XLA scatter — the hybrid layout described in ell_spmv.py.
-
-    The kernel *gathers*: y[v] = Σ_k wgt[v,k]·p[nbr[v,k]], so the diffusion
-    push into v along edge (w → v) carries weight coef/d(w) — the
-    **neighbor's** degree (coef=0.5 for the lazy-walk half-push).  Gather
-    over the symmetric adjacency is exactly the push accumulation, without
-    any scatter in the hot path.
-    """
-    g = graph.to_numpy()
-    n = g.n
-    n_pad = -(-n // ROW_BLOCK) * ROW_BLOCK
-    src = np.repeat(np.arange(n), g.deg)
-    dst = g.indices[: 2 * g.m]
-    in_band = np.abs(src // ROW_BLOCK - dst // ROW_BLOCK) <= halo
-    # ELL width = max band-degree
-    band_deg = np.bincount(src[in_band], minlength=n_pad).astype(np.int64)
-    W = max(int(band_deg.max()), 1)
-    nbr = np.full((n_pad, W), n_pad, dtype=np.int32)
-    wgt = np.zeros((n_pad, W), dtype=np.float32)
-    slot = np.zeros(n_pad, dtype=np.int64)
-    bs, bd = src[in_band], dst[in_band]
-    for s, d in zip(bs, bd):
-        nbr[s, slot[s]] = d
-        wgt[s, slot[s]] = coef / g.deg[d]   # neighbor's degree: push d → s
-        slot[s] += 1
-    esc_src = src[~in_band].astype(np.int32)
-    esc_dst = dst[~in_band].astype(np.int32)
-    esc_w = (coef / g.deg[esc_dst]).astype(np.float32)
-    return (jnp.asarray(nbr), jnp.asarray(wgt),
-            jnp.asarray(esc_src), jnp.asarray(esc_dst), jnp.asarray(esc_w),
-            n_pad, W)
+def _check_32bit(x) -> None:
+    if jnp.dtype(x.dtype).itemsize != 4:
+        raise TypeError(f"the Pallas kernels take 32-bit dtypes, got {x.dtype}")
 
 
-@functools.partial(jax.jit, static_argnames=("halo",))
-def diffusion_spmv(nbr, wgt, esc_src, esc_dst, esc_w, p, halo: int = 1):
-    """One saturated diffusion product y = coef·(A D⁻¹)p on the hybrid layout:
-    banded ELL via the Pallas kernel + escaper COO via XLA scatter."""
-    y = band_spmv(nbr, wgt, p, halo=halo, interpret=_interp())
-    contrib = esc_w * p[esc_dst]            # gather semantics: pull d → s
-    return y.at[esc_src].add(contrib)
+def scatter_fold(vec: jnp.ndarray, idx: jnp.ndarray,
+                 vals: jnp.ndarray) -> jnp.ndarray:
+    """``vec.at[idx].add(vals, mode="drop")`` folded in submission order.
 
-
-def scatter_add_via_mxu(vec: jnp.ndarray, idx: jnp.ndarray, vals: jnp.ndarray,
-                        chunk: int = 256) -> jnp.ndarray:
-    """Dense scatter-add through the sort-bucket-MXU pipeline.
-
-    Sorts (idx, vals) by destination, buckets into 128-wide tiles with a
-    fixed per-tile chunk, runs the Pallas accumulation kernel, and adds the
-    tile updates back with one contiguous reshape — semantically equal to
-    ``vec.at[idx].add(vals)`` (ref: kernels/ref.py::scatter_accum_ref).
-
-    Per-tile overflow (more than ``chunk`` contributions landing in one
-    tile) falls back to XLA scatter for the overflowing remainder.
-    """
-    n = vec.shape[0]
-    n_pad = -(-n // TILE) * TILE
-    T = n_pad // TILE
-    order = jnp.argsort(idx)
-    idx_s = idx[order]
-    vals_s = vals[order]
-    tile_id = jnp.clip(idx_s // TILE, 0, T - 1)
-    # rank within tile: position - first position of tile
-    first_pos = jnp.searchsorted(tile_id, jnp.arange(T), side="left")
-    rank = jnp.arange(idx.shape[0]) - first_pos[tile_id]
-    ok = (idx_s >= 0) & (idx_s < n) & (rank < chunk)
-    flat = tile_id * chunk + rank
-    local = jnp.full((T * chunk,), -1, jnp.int32).at[
-        jnp.where(ok, flat, T * chunk)].set(
-        (idx_s % TILE).astype(jnp.int32), mode="drop")
-    v = jnp.zeros((T * chunk,), jnp.float32).at[
-        jnp.where(ok, flat, T * chunk)].set(vals_s, mode="drop")
-    tiles = scatter_accum_tiles(local.reshape(T, chunk), v.reshape(T, chunk),
-                                interpret=_interp())
-    out = vec + tiles.reshape(-1)[:n]
-    # overflow remainder via XLA scatter (rare; correctness-preserving)
-    spill = (~ok) & (idx_s >= 0) & (idx_s < n)
-    out = out.at[jnp.where(spill, idx_s, n)].add(
-        jnp.where(spill, vals_s, 0.0), mode="drop")
-    return out
-
-
-def scatter_fold_via_mxu(vec: jnp.ndarray, idx: jnp.ndarray,
-                         vals: jnp.ndarray, chunk: int = 256) -> jnp.ndarray:
-    """Update-order-preserving scatter-add through the MXU kernel.
-
-    Same sort-bucket-matmul pipeline as :func:`scatter_add_via_mxu`, but each
-    128-wide destination tile's *existing* ``vec`` values are prepended as the
-    tile's first 128 (identity-offset) contributions, so every output element
-    is the left fold ``((vec[i] + v_1) + v_2) + …`` with the contributions in
-    their original submission order (the stable sort preserves it) — exactly
-    the combine order of ``vec.at[idx].add(vals)``.  This is the bit-exact
-    variant :mod:`repro.core.ops` routes drivers through; the plain
-    ``vec + tiles`` variant above keeps the cheaper layout for callers that
-    only need allclose.
-
-    Per-tile overflow (more than ``chunk`` contributions on one tile) spills
-    to an XLA scatter *after* the tile fold — those are the latest-sorted
-    contributions per destination, so fold order is still preserved.
-    """
-    n = vec.shape[0]
-    m = idx.shape[0]
-    n_pad = -(-n // TILE) * TILE
-    T = n_pad // TILE
-    C = TILE + chunk
-    order = jnp.argsort(idx)               # stable: preserves submission order
-    idx_s = idx[order]
-    vals_s = vals[order]
-    tile_id = jnp.clip(idx_s // TILE, 0, T - 1)
-    first_pos = jnp.searchsorted(tile_id, jnp.arange(T), side="left")
-    rank = jnp.arange(m) - first_pos[tile_id]
-    ok = (idx_s >= 0) & (idx_s < n) & (rank < chunk)
-    # identity block: slot j < TILE of tile t carries vec[t*TILE + j]
-    local = jnp.broadcast_to(
-        jnp.concatenate([jnp.arange(TILE, dtype=jnp.int32),
-                         jnp.full((chunk,), -1, jnp.int32)]), (T, C))
-    v = jnp.concatenate(
-        [jnp.pad(vec.astype(jnp.float32), (0, n_pad - n)).reshape(T, TILE),
-         jnp.zeros((T, chunk), jnp.float32)], axis=1)
-    flat = tile_id * C + TILE + rank
-    local = local.reshape(-1).at[jnp.where(ok, flat, T * C)].set(
-        (idx_s % TILE).astype(jnp.int32), mode="drop").reshape(T, C)
-    v = v.reshape(-1).at[jnp.where(ok, flat, T * C)].set(
-        vals_s.astype(jnp.float32), mode="drop").reshape(T, C)
-    tiles = scatter_accum_tiles(local, v, interpret=_interp())
-    out = tiles.reshape(-1)[:n]
-    spill = (~ok) & (idx_s >= 0) & (idx_s < n)
-    out = out.at[jnp.where(spill, idx_s, n)].add(
-        jnp.where(spill, vals_s.astype(jnp.float32), 0.0), mode="drop")
-    return out
+    Every destination receives its contributions as the left fold
+    ``((vec[i] + v_1) + v_2) + …`` in the order they appear in ``idx``
+    (stable sort), however many there are, so the result equals an XLA
+    scatter that combines in update order, bit for bit.  Nothing is handed
+    to XLA.  Indices outside ``[0, n)`` are dropped.
+    ``vec`` is any 32-bit dtype; ``vals`` is cast to it."""
+    _check_32bit(vec)
+    n, m = vec.shape[0], idx.shape[0]
+    if m == 0:
+        return vec
+    G, B = scatter_accum.GROUP, scatter_accum.BLOCK
+    groups = -(-n // G)
+    key = jnp.where((idx >= 0) & (idx < n), idx, n).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)   # keeps submission order per key
+    key_s = key[order]
+    # group g's contributions are stream elements bounds[g] … bounds[g+1]-1
+    # (dropped ones sort last, past bounds[groups])
+    bounds = jnp.searchsorted(
+        key_s, jnp.minimum(jnp.arange(groups + 1, dtype=jnp.int32) * G, n),
+        side="left").astype(jnp.int32)
+    pad = -m % B
+    dest = jnp.pad(key_s % G, (0, pad)).reshape(-1, scatter_accum.CHUNK)
+    v = jnp.pad(vals[order].astype(vec.dtype), (0, pad)).reshape(
+        -1, scatter_accum.CHUNK)
+    vec2d = jnp.pad(vec, (0, groups * G - n)).reshape(-1, scatter_accum.LANES)
+    return scatter_accum.scatter_fold_groups(
+        bounds[None], dest, v, vec2d, interpret=interpret()).reshape(-1)[:n]
 
 
 def prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive prefix sum via the blocked Pallas scan (auto-padded)."""
+    """Inclusive prefix sum through the blocked scan kernel, dtype
+    preserved: int32 results equal ``jnp.cumsum`` bit for bit; f32 scans
+    reassociate."""
+    _check_32bit(x)
     n = x.shape[0]
-    n_pad = -(-n // BLOCK) * BLOCK
-    xp = jnp.pad(x.astype(jnp.float32), (0, n_pad - n))
-    return block_scan(xp, interpret=_interp())[:n]
+    if n == 0:
+        return x
+    pad = -n % prefix_scan.BLOCK
+    x2d = jnp.pad(x, (0, pad)).reshape(-1, prefix_scan.LANES)
+    return prefix_scan.block_scan(x2d, interpret=interpret()).reshape(-1)[:n]
 
 
-def prefix_sum_exact(x: jnp.ndarray) -> jnp.ndarray:
-    """Dtype-preserving inclusive prefix sum via the blocked Pallas scan.
+def segment_merge_sorted(ids_s, vals_s, n: int, cap: int):
+    """Sum duplicate runs of a *sorted* id stream and compact to ``cap``.
 
-    Unlike :func:`prefix_sum` there is no f32 cast: integer inputs scan in
-    integer arithmetic, so the result is bit-identical to ``jnp.cumsum``
-    regardless of the block association (the op layer's exactness contract
-    for the drivers' int32 scans)."""
-    n = x.shape[0]
-    n_pad = -(-n // BLOCK) * BLOCK
-    xp = jnp.pad(x, (0, n_pad - n))
-    return block_scan(xp, interpret=_interp())[:n]
+    Args:
+      ids_s:  int32[tot] sorted ascending; entries ≥ ``n`` are sentinels.
+      vals_s: f32[tot] values aligned with ``ids_s``.
+      n:      sentinel threshold (one past the last valid id).
+      cap:    output capacity.
+    Returns:
+      ``(out_ids int32[cap], out_vals f32[cap], count int32)`` — unique ids
+      ascending with per-id totals folded in stream order, sentinel-``n`` /
+      zero padded; ``count`` is the *uncapped* number of unique ids.  The
+      output contract of :func:`repro.core.ops.segment_merge`.
+    """
+    tot = ids_s.shape[0]
+    step = segment_merge.SUB * segment_merge.BLK
+    pad = -tot % step if tot else step
+    ids_p = jnp.concatenate([ids_s.astype(jnp.int32),
+                             jnp.full((pad,), n, jnp.int32)])
+    vals_p = jnp.concatenate([vals_s.astype(jnp.float32),
+                              jnp.zeros((pad,), jnp.float32)])
+    prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), ids_p[:-1]])
+    nxt = jnp.concatenate([ids_p[1:], jnp.full((1,), -2, jnp.int32)])
+    first = (ids_p != prev).astype(jnp.int32)
+    keep = (ids_p != nxt) & (ids_p < n)
+    run = segment_merge.fold_runs(
+        first.reshape(-1, segment_merge.BLK),
+        vals_p.reshape(-1, segment_merge.BLK),
+        interpret=interpret()).reshape(-1)
+    rank = prefix_sum(keep.astype(jnp.int32))
+    count = rank[-1]
+    at = jnp.where(keep, rank - 1, cap)
+    out_ids = jnp.full((cap,), n, jnp.int32).at[at].set(ids_p, mode="drop")
+    out_vals = jnp.zeros((cap,), jnp.float32).at[at].set(run, mode="drop")
+    return out_ids, out_vals, count

@@ -1,17 +1,18 @@
 """Blocked prefix-sum Pallas kernel — the sweep cut's backbone.
 
-Prefix sum is one of the paper's three foundational primitives (§3) and the
-core of Theorem 1's sweep cut (cut sizes, volumes, and the final prefix-min
-are all scans).  XLA lowers ``cumsum`` to O(n log n) shifted adds or a
-serialized loop; this kernel is the classic two-phase work-efficient scan
-mapped to TPU VMEM blocks:
+Prefix sum is one of the paper's three foundational primitives (§3): the
+frontier's edge offsets, the compaction ranks and the sweep cut's cut sizes
+and volumes are all scans.  The kernel scans a ``[R, 128]`` view of the
+array in ``ROWS × 128`` blocks on one sequential grid axis:
 
-  phase 1 — per-block inclusive scan + block total   (this kernel, grid pass)
-  phase 2 — tiny exclusive scan of block totals      (jnp on <= grid elems)
-  phase 3 — add block offsets                        (this kernel again)
+  * within a block, a log-step shifted add along the lanes (7 steps of
+    ``pltpu.roll``) scans every row, and the same along the sublanes scans
+    the row totals — Hillis–Steele, O(log) depth per block;
+  * a ``[1, 128]`` VMEM scratch carries the running total from one block
+    to the next (the grid axis is ``arbitrary``, i.e. sequential).
 
-Work O(n), depth O(log n) — Blelloch's bounds, realized with VMEM-resident
-blocks of 8·128 lanes × UNROLL rows.
+The scan keeps its input's dtype: integer scans are exact, so int32 results
+equal ``jnp.cumsum`` bit for bit; float scans reassociate.
 """
 from __future__ import annotations
 
@@ -20,50 +21,53 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["block_scan", "BLOCK"]
+__all__ = ["block_scan", "LANES", "ROWS", "BLOCK"]
 
-BLOCK = 2048  # elements per VMEM block (16 sublane rows × 128 lanes)
+LANES = 128
+ROWS = 8
+BLOCK = ROWS * LANES   # elements per grid step
 
 
-def _scan_block_kernel(x_ref, y_ref, tot_ref):
+def _scan_kernel(x_ref, y_ref, carry_ref):
+    @pl.when(pl.program_id(0) == 0)
+    def _reset():
+        carry_ref[...] = jnp.zeros(carry_ref.shape, carry_ref.dtype)
+
     x = x_ref[...]
-    y = jnp.cumsum(x)
-    y_ref[...] = y
-    tot_ref[0] = y[-1]
-
-
-def _add_offsets_kernel(y_ref, off_ref, out_ref):
-    out_ref[...] = y_ref[...] + off_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    zero = jnp.zeros_like(x)
+    y = x
+    for s in (1, 2, 4, 8, 16, 32, 64):              # scan each row
+        y = y + jnp.where(lane >= s, pltpu.roll(y, s, 1), zero)
+    tot = jnp.broadcast_to(y[:, LANES - 1:], x.shape)
+    z = tot
+    s = 1
+    while s < ROWS:                                 # scan the row totals
+        z = z + jnp.where(row >= s, pltpu.roll(z, s, 0), zero)
+        s *= 2
+    out = y + (z - tot) + carry_ref[...]
+    y_ref[...] = out
+    carry_ref[...] = jnp.broadcast_to(out[ROWS - 1:, LANES - 1:],
+                                      carry_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def block_scan(x: jnp.ndarray, interpret: bool = False) -> jnp.ndarray:
-    """Inclusive prefix sum of f32[n] (n multiple of BLOCK)."""
-    n = x.shape[0]
-    assert n % BLOCK == 0, f"pad input to a multiple of {BLOCK}"
-    nb = n // BLOCK
-
-    y, totals = pl.pallas_call(
-        _scan_block_kernel,
-        out_shape=(jax.ShapeDtypeStruct((n,), x.dtype),
-                   jax.ShapeDtypeStruct((nb,), x.dtype)),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,))],
-        out_specs=(pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                   pl.BlockSpec((1,), lambda i: (i,))),
-        interpret=interpret,
-    )(x)
-
-    # phase 2: exclusive scan of the nb block totals (tiny)
-    offsets = jnp.cumsum(totals) - totals
-
+def block_scan(x2d: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
+    """Inclusive row-major prefix sum of ``x2d`` ([R, 128], R a multiple of
+    :data:`ROWS`), dtype preserved."""
+    rows = x2d.shape[0]
+    assert x2d.shape[1] == LANES and rows % ROWS == 0, x2d.shape
     return pl.pallas_call(
-        _add_offsets_kernel,
-        out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                  pl.BlockSpec((1,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
+        _scan_kernel,
+        out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
+        grid=(rows // ROWS,),
+        in_specs=[pl.BlockSpec((ROWS, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((1, LANES), x2d.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(y, offsets)
+    )(x2d)

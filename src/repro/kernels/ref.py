@@ -1,40 +1,15 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness contracts).
+"""Plain oracles for the kernels and ops (the correctness contracts).
 
-Each function computes the same mathematical object as its kernel with plain
-jax.numpy — no tiling, no VMEM reasoning — and is what the per-kernel
-shape/dtype sweep tests assert against (``tests/test_kernels.py``).
+Each function computes the same mathematical object as a kernel or op with
+host numpy loops or plain jax.numpy — no tiling, no sorting pipeline — and
+is what the parity tests assert against (``tests/test_kernels.py``,
+``tests/test_ops.py``).
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["band_spmv_ref", "scatter_accum_ref", "block_scan_ref",
-           "spmv_csr_ref", "scatter_add_ref", "segment_merge_ref"]
-
-
-def band_spmv_ref(nbr: jnp.ndarray, weights: jnp.ndarray,
-                  p: jnp.ndarray) -> jnp.ndarray:
-    """y[v] = Σ_k weights[v,k] · p[nbr[v,k]]; sentinel ids carry weight 0."""
-    n = p.shape[0]
-    safe = jnp.clip(nbr, 0, n - 1)
-    vals = p[safe] * (nbr < n) * (nbr >= 0)
-    return jnp.sum(vals * weights, axis=1)
-
-
-def scatter_accum_ref(local: jnp.ndarray, vals: jnp.ndarray,
-                      tile: int = 128) -> jnp.ndarray:
-    """out[t, c] = Σ_j vals[t, j] · [local[t, j] == c]."""
-    T, C = local.shape
-    out = jnp.zeros((T, tile), jnp.float32)
-    ok = (local >= 0) & (local < tile)
-    t_idx = jnp.repeat(jnp.arange(T), C)
-    c_idx = jnp.where(ok, local, 0).reshape(-1)
-    v = jnp.where(ok, vals, 0.0).reshape(-1)
-    return out.at[t_idx, c_idx].add(v)
-
-
-def block_scan_ref(x: jnp.ndarray) -> jnp.ndarray:
-    return jnp.cumsum(x)
+__all__ = ["scatter_add_ref", "segment_merge_ref", "fold_runs_ref"]
 
 
 def scatter_add_ref(vec, idx, vals, valid):
@@ -73,11 +48,16 @@ def segment_merge_ref(ids, vals, n: int, cap: int):
     return out_ids, out_vals, count
 
 
-def spmv_csr_ref(indptr, indices, deg, p, coef: float = 0.5):
-    """Dense reference for the full diffusion matrix–vector product
-    p' = coef·(A D⁻¹)p (+ the self term added by the caller)."""
-    n = deg.shape[0]
-    out = jnp.zeros_like(p)
-    src = jnp.repeat(jnp.arange(n), deg, total_repeat_length=indices.shape[0])
-    contrib = coef * p[src] / jnp.maximum(deg[src], 1)
-    return out.at[indices].add(contrib)
+def fold_runs_ref(first, vals):
+    """Running per-run left fold ``s_j = (first_j ? 0 : s_{j-1}) + v_j`` of
+    :func:`repro.kernels.segment_merge.fold_runs`, as a host-side numpy
+    loop.  Test-only (eager numpy, not jit-able)."""
+    import numpy as np
+    first = np.asarray(first).reshape(-1)
+    vals = np.asarray(vals, np.float32).reshape(-1)
+    out = np.empty_like(vals)
+    s = np.float32(0.0)
+    for j in range(vals.shape[0]):
+        s = (np.float32(0.0) if first[j] else s) + vals[j]
+        out[j] = s
+    return out

@@ -1,39 +1,24 @@
-"""Fused sorted-segment-merge Pallas kernel — ``sv_merge_add``'s hot loop.
+"""Segmented left-fold Pallas kernel — ``sv_merge_add``'s run reduction.
 
 The sparse backend's merge-add (sparsevec.py) is the paper's batched hash
-insert: concat → sort → sum-adjacent-duplicates → compact.  The sort is an
-XLA native (TPU sort is fast); everything *after* the sort is a chain of four
-XLA ops (compare-shift, cumsum-group, segment_sum scatter, compaction
-scatter) that each round-trips HBM.  This kernel fuses the O(N) post-sort
-reduction into one pass over the sorted stream:
+insert: concat → sort → sum adjacent duplicates → compact.  The sort is an
+XLA native and the compaction is a scan plus a scatter; what remains is the
+per-run reduction, which XLA expresses as a ``segment_sum`` scatter whose
+combine order is stream order.  This kernel computes that reduction as the
+running fold
 
-  * the stream is processed in VMEM blocks of ``BLK`` elements by an
-    in-kernel ``fori_loop`` (one ``pallas_call`` program — vmap-safe: a
-    batched call gives every lane its own loop and carries);
-  * per block, run lengths become a local segment id by a cumsum over
-    boundary flags, and the per-segment totals are computed with a one-hot
-    contraction on the MXU — ``vals[1, BLK+1] @ onehot[BLK+1, BLK+1]`` —
-    exactly the associativity trick of ``scatter_accum.py``;
-  * segments spanning block boundaries are stitched by a carried scalar:
-    the open segment's running sum is *prepended* to the next block's
-    contraction operand, so every run is reduced as the left fold
-    ``((v_1 + v_2) + v_3) + …`` in stream order — the same combine order as
-    XLA's ``segment_sum`` scatter, which is what makes the ``pallas`` and
-    ``xla`` op backends bit-identical (validated in interpret mode; on real
-    MXUs the contraction order is the hardware's);
-  * a second one-hot contraction places each run's total at its *last*
-    stream position, and a carried int cumsum assigns each kept run its
-    compacted output slot.
+    s_j = (first_j ? 0 : s_{j-1}) + v_j
 
-The wrapper :func:`segment_merge_sorted` owns the layout work (boundary
-flags, padding, final compaction scatter) so callers deal in sorted-stream
-terms; :mod:`repro.core.ops` routes ``SparseVec`` merges here under
-``backend="pallas"``.
+over the sorted stream, so ``s`` at each run's last position is the run's
+total folded ``((0 + v_1) + v_2) + …`` — the same additions in the same
+order as ``segment_sum``, bit for bit.
 
-VMEM note: the whole stream lives in VMEM for the duration of the program
-(~16 B/element across the five refs), so streams up to ~10⁶ elements fit
-comfortably; the capacity-ladder extremes (cap_e ≳ 2²²) should stay on the
-``xla`` backend.
+The stream is viewed as ``[R, BLK]`` and walked in ``SUB × BLK`` SMEM
+blocks on one sequential grid axis; an SMEM scalar carries the open run's
+sum from one block to the next, so the stream never has to fit on chip.
+The fold runs on the scalar unit: exact by construction, one element per
+step.  :func:`repro.kernels.ops.segment_merge_sorted` owns the layout work
+(run flags, padding, compaction).
 """
 from __future__ import annotations
 
@@ -42,119 +27,53 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["segment_merge_stream", "segment_merge_sorted", "BLK"]
+__all__ = ["fold_runs", "SUB", "BLK"]
 
-BLK = 256  # stream elements per fori_loop step (one-hot tiles are BLK+1 wide)
+SUB = 8      # SMEM block rows (the (8, 128) tiling rule)
+BLK = 512    # SMEM block columns: SUB·BLK stream elements per grid step
 
 
-def _merge_kernel(first_ref, last_ref, sel_ref, vals_ref, tot_ref, rank_ref):
-    """One program: left-fold run totals + compaction ranks over the stream.
+def _fold_runs_kernel(first_ref, vals_ref, out_ref, acc_ref):
+    @pl.when(pl.program_id(0) == 0)
+    def _reset():
+        acc_ref[0] = jnp.float32(0.0)
 
-    Inputs (all length ``nb·BLK``):
-      first_ref: int32 — 1 where a run starts (ids[j] != ids[j-1])
-      last_ref:  int32 — 1 where a run ends   (ids[j] != ids[j+1])
-      sel_ref:   int32 — 1 at run ends of runs that are kept (id < sentinel)
-    Outputs:
-      tot_ref:  f32   — run total at each run's last position, 0 elsewhere
-      rank_ref: int32 — inclusive count of kept runs up to each position
-    """
-    nb = first_ref.shape[0] // BLK
-    col = jax.lax.broadcasted_iota(jnp.int32, (BLK + 1, BLK + 1), 1)
-    pick_row = jax.lax.broadcasted_iota(jnp.int32, (BLK + 1, BLK), 0)
-    col1 = jax.lax.broadcasted_iota(jnp.int32, (1, BLK + 1), 1)
+    sub, blk = first_ref.shape
 
-    def body(i, carry):
-        open_sum, rank0 = carry
-        off = i * BLK
-        first = first_ref[pl.ds(off, BLK)]
-        last = last_ref[pl.ds(off, BLK)]
-        sel = sel_ref[pl.ds(off, BLK)]
-        vals = vals_ref[pl.ds(off, BLK)]
+    def body(j, s):
+        i, k = j // blk, j % blk
+        s = jnp.where(first_ref[i, k] != 0, jnp.float32(0.0), s) + vals_ref[i, k]
+        out_ref[i, k] = s
+        return s
 
-        # local segment id: 0 = segment carried open from the previous block
-        g = jnp.cumsum(first)
-        # prepend the carried running sum so block-spanning runs reduce as
-        # one left fold in stream order (bit-identical to segment_sum)
-        vext = jnp.concatenate([open_sum.reshape(1), vals])
-        gext = jnp.concatenate([jnp.zeros((1,), jnp.int32), g])
-        seg_oh = (col == gext[:, None]).astype(jnp.float32)
-        part = jax.lax.dot_general(
-            vext.reshape(1, BLK + 1), seg_oh, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [1, BLK+1] run sums
-
-        # place each run's total at its last position (exact: one-hot pick)
-        pick_oh = ((pick_row == g[None, :]) & (last[None, :] == 1)
-                   ).astype(jnp.float32)
-        totals = jax.lax.dot_general(
-            part, pick_oh, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).reshape(BLK)
-
-        rank = rank0 + jnp.cumsum(sel)
-        tot_ref[pl.ds(off, BLK)] = totals
-        rank_ref[pl.ds(off, BLK)] = rank
-
-        open_next = jnp.sum(jnp.where(col1 == g[BLK - 1], part, 0.0))
-        open_next = jnp.where(last[BLK - 1] == 1, 0.0, open_next)
-        return open_next.astype(jnp.float32), rank[BLK - 1]
-
-    jax.lax.fori_loop(0, nb, body,
-                      (jnp.float32(0.0), jnp.asarray(0, jnp.int32)))
+    acc_ref[0] = jax.lax.fori_loop(0, sub * blk, body, acc_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def segment_merge_stream(first, last, sel, vals, interpret: bool = False):
-    """Run the fused kernel over a boundary-flagged sorted stream.
-
-    All inputs are length ``tot`` (a multiple of :data:`BLK`); returns
-    ``(totals f32[tot], rank int32[tot])`` as documented on the kernel.
-    """
-    tot = vals.shape[0]
-    assert tot % BLK == 0, f"pad the stream to a multiple of {BLK}"
-    return pl.pallas_call(
-        _merge_kernel,
-        out_shape=(jax.ShapeDtypeStruct((tot,), jnp.float32),
-                   jax.ShapeDtypeStruct((tot,), jnp.int32)),
-        interpret=interpret,
-    )(first, last, sel, vals)
-
-
-@functools.partial(jax.jit, static_argnames=("n", "cap", "interpret"))
-def segment_merge_sorted(ids_s, vals_s, n: int, cap: int,
-                         interpret: bool = False):
-    """Sum duplicate runs of a *sorted* id stream and compact to ``cap``.
+def fold_runs(first: jnp.ndarray, vals: jnp.ndarray, *,
+              interpret: bool) -> jnp.ndarray:
+    """Running per-run left fold of a run-flagged stream.
 
     Args:
-      ids_s:  int32[tot] sorted ascending; entries ≥ ``n`` are sentinels.
-      vals_s: f32[tot] values aligned with ``ids_s``.
-      n:      sentinel threshold (one past the last valid id).
-      cap:    output capacity.
+      first: int32[R, BLK] — 1 where a run starts (row-major stream order;
+             the first element must start a run).  R is a multiple of SUB.
+      vals:  f32[R, BLK]   — the values.
     Returns:
-      ``(out_ids int32[cap], out_vals f32[cap], count int32)`` — unique ids
-      sorted ascending with per-id totals, sentinel-``n``/zero padded;
-      ``count`` is the *uncapped* number of unique ids (callers compare it
-      with ``cap`` for overflow).  Identical output contract (and, per run,
-      identical f32 fold order) to the ``xla`` merge in
-      :func:`repro.core.ops.segment_merge`.
+      f32[R, BLK] — ``s_j`` as in the module docstring.
     """
-    tot = ids_s.shape[0]
-    pad = (-tot) % BLK
-    ids_p = jnp.concatenate([ids_s.astype(jnp.int32),
-                             jnp.full((pad,), n, jnp.int32)])
-    vals_p = jnp.concatenate([vals_s.astype(jnp.float32),
-                              jnp.zeros((pad,), jnp.float32)])
-    prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), ids_p[:-1]])
-    nxt = jnp.concatenate([ids_p[1:], jnp.full((1,), -2, jnp.int32)])
-    first = (ids_p != prev).astype(jnp.int32)
-    last = (ids_p != nxt).astype(jnp.int32)
-    keep = (last == 1) & (ids_p < n)
-    totals, rank = segment_merge_stream(first, last,
-                                        keep.astype(jnp.int32), vals_p,
-                                        interpret=interpret)
-    count = rank[-1]
-    pos = rank - 1
-    out_ids = jnp.full((cap,), n, jnp.int32).at[
-        jnp.where(keep, pos, cap)].set(ids_p, mode="drop")
-    out_vals = jnp.zeros((cap,), jnp.float32).at[
-        jnp.where(keep, pos, cap)].set(totals, mode="drop")
-    return out_ids, out_vals, count
+    rows, blk = first.shape
+    assert rows % SUB == 0, first.shape
+    spec = pl.BlockSpec((SUB, blk), lambda i: (i, 0), memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        _fold_runs_kernel,
+        out_shape=jax.ShapeDtypeStruct(vals.shape, jnp.float32),
+        grid=(rows // SUB,),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(first, vals)

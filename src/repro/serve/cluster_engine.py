@@ -62,9 +62,10 @@ PR-Nibble (β = 1): HK-PR or β-selection requests always serve dense.
 Orthogonal to the lane type is the *kernel* backend
 (``ops_backend="xla" | "pallas" | "auto"``, engine-wide or per request via
 ``ClusterRequest.ops_backend``): which implementation every scatter/merge/
-scan inside the rounds dispatches to (:mod:`repro.core.ops`).  Results are
-bit-identical across kernel backends, so the scheduler may serve a mixed
-stream from differently-backed pools without changing any answer.
+scan inside the rounds dispatches to (:mod:`repro.core.ops`); "auto" is
+"xla" on every platform.  The kernel backend is pool-key and result-cache
+material: the backends agree bit for bit where XLA folds in update order
+(guarantee #6), which is shown on the CPU, not promised everywhere.
 
 Scheduling surface
 ------------------
@@ -142,7 +143,7 @@ class ClusterRequest:
     backend: Optional[str] = None  # None = engine default; "dense" | "sparse"
     ops_backend: Optional[str] = None  # None = engine default; "xla" |
     #   "pallas" | "auto" — kernel backend (repro.core.ops), orthogonal to
-    #   the dense/sparse lane choice; results are bit-identical across it
+    #   the dense/sparse lane choice ("auto" is "xla")
     # Scheduling hints, consumed by serve/scheduler.py's AsyncClusterEngine
     # (the synchronous engine ignores them).  Never part of a pool key:
     # deadlines/priorities order work, they never select a compiled program.
@@ -554,11 +555,11 @@ class LocalClusterEngine:
         ``cap_v`` is the sparse lanes' value capacity K at bucket 0;
         ``cap_x`` is the dist lanes' per-owner exchange-bucket capacity at
         bucket 0.  ``ops_backend`` is the engine-wide default *kernel*
-        backend ("xla" | "pallas" | "auto" → TPU? pallas : xla) — orthogonal
-        to the lane type; requests may pin their own via
+        backend ("xla" | "pallas" | "auto" → "xla") — orthogonal to the
+        lane type; requests may pin their own via
         ``ClusterRequest.ops_backend``.  Results are bit-identical across
-        kernel backends *and* across lane backends for the dense/dist pair,
-        so mixing them in one stream is safe.
+        lane backends for the dense/dist pair (guarantee #7) and across
+        kernel backends where XLA folds in update order (guarantee #6).
 
         ``tracer`` (a :class:`repro.serve.tracing.Tracer`, default None =
         tracing off) records a span tree per request and per-tick pool
@@ -718,7 +719,7 @@ class LocalClusterEngine:
         if self.result_cache is None:
             return None
         key = result_key(req, self._resolve_backend(req),
-                         self.handle.version)
+                         self._resolve_ops_backend(req), self.handle.version)
         res = self.result_cache.get(key, request=req)
         self.stats["result_cache_hits"] = self.result_cache.hits
         self.stats["result_cache_misses"] = self.result_cache.misses
@@ -814,7 +815,8 @@ class LocalClusterEngine:
         self.stats["completed"] += 1
         if self.result_cache is not None and not res.deadline_missed:
             self.result_cache.put(
-                result_key(res.request, res.backend, self.handle.version),
+                result_key(res.request, res.backend, res.ops_backend,
+                           self.handle.version),
                 res)
         rt = self._rt.get(idx)
         if rt is not None:

@@ -12,9 +12,12 @@ Key design (:func:`result_key`):
     :attr:`repro.graphs.handle.GraphHandle.version` when the graph's
     edges change, which makes every cached community stale at once (old
     versions age out of the LRU; no scan-and-purge).
-  * The *kernel* backend (ops_backend) is excluded: results are
-    bit-identical across it (docs/algorithms.md, guarantee #6), so an xla
-    hit may serve a pallas request and vice versa.
+  * The resolved *kernel* backend (ops_backend) is part of the key.  The
+    ``pallas`` kernels fold in XLA's combine order, so the two backends
+    agree bit for bit where XLA's scatter folds in update order, as on the
+    CPU (docs/algorithms.md, guarantee #6); on a TPU that is a measured
+    finding, not a guarantee, so an ``xla`` answer never serves a
+    ``pallas`` request or the reverse.
   * The *lane* backend is folded to its bit-identity class: dense and dist
     lanes produce bit-identical rows (guarantee #7) and share entries;
     sparse lanes run the sparse update order and key separately
@@ -39,18 +42,20 @@ from typing import Optional
 __all__ = ["ResultCache", "result_key"]
 
 
-def result_key(req, lane_backend: str, graph_version: int = 0) -> tuple:
+def result_key(req, lane_backend: str, ops_backend: str,
+               graph_version: int = 0) -> tuple:
     """Cache key for one request: ``(graph_version, method, seed, α, ε,
-    statics, lane-identity-class)``.  ``lane_backend`` is the *resolved*
-    lane type ("dense" | "sparse" | "dist" — never "auto"); dense and dist
-    collapse to one class (bit-identical rows, guarantee #7)."""
+    statics, lane-identity-class, ops_backend)``.  ``lane_backend`` is the
+    *resolved* lane type ("dense" | "sparse" | "dist" — never "auto"); dense
+    and dist collapse to one class (bit-identical rows, guarantee #7).
+    ``ops_backend`` is the resolved kernel backend ("xla" | "pallas")."""
     if req.method == "pr_nibble":
         statics = (req.optimized, req.beta)
     else:
         statics = (req.N, req.t)
     family = "sparse" if lane_backend == "sparse" else "dense"
     return (graph_version, req.method, int(req.seed), float(req.alpha),
-            float(req.eps), statics, family)
+            float(req.eps), statics, family, ops_backend)
 
 
 class ResultCache:

@@ -13,10 +13,12 @@ def run_subprocess_json(script: str, timeout: int = 900) -> dict:
     """Run a python script in a subprocess and parse its ``RESULT:<json>``
     line — the shared recipe for the 8-host-device distributed tests
     (the child sets its own ``XLA_FLAGS`` device count before importing
-    jax, so the parent's flags are scrubbed to keep the recipe hermetic)."""
+    jax, so the parent's flags are scrubbed to keep the recipe hermetic,
+    and it is pinned to the CPU: the parent may hold the chip)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # virtual CPU devices, never the chip
     proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=timeout)
     assert proc.returncode == 0, proc.stderr[-4000:]

@@ -44,6 +44,7 @@ print("RESULT:" + json.dumps({
 def test_pp_forward_matches_sequential():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"   # virtual CPU devices, never the chip
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]

@@ -15,8 +15,7 @@ from repro.serve import ClusterRequest, LocalClusterEngine
 
 
 def _unregister(listener) -> None:
-    from jax._src import monitoring
-    monitoring._unregister_event_duration_listener_by_callback(listener)
+    jax.monitoring.unregister_event_duration_listener(listener)
 
 
 def test_steady_state_stream_never_recompiles(sbm_graph):
