@@ -1,0 +1,7 @@
+"""Lane pools and tick: mean duration of the HK-PR pools' ``tick`` spans
+that started in the window, in ms."""
+from bench.program import tick_ms
+
+
+def read(run):
+    return tick_ms(run, "hk_pr")

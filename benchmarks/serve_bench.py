@@ -30,14 +30,6 @@ Emits the usual `name,us_per_call,derived` CSV rows (us = p50 latency) and
 returns a JSON-able dict that `benchmarks/run.py` writes to
 ``BENCH_serve.json`` — the artifact CI uploads so the serving-latency
 trajectory accumulates across PRs.
-
-``--trace`` additionally flight-records every request through a
-:class:`repro.serve.tracing.Tracer` and writes ``BENCH_trace.json``:
-Chrome trace events (load in Perfetto), a per-request phase-attribution
-table (queued / pool_queue / resident / sweep / deliver, with coverage =
-how much of the measured wall latency the spans explain), the
-deadline-miss postmortems from the telemetry snapshot, and a purity probe
-asserting the traced stream is bit-identical to an untraced one.
 """
 from __future__ import annotations
 
@@ -48,9 +40,8 @@ import time
 import numpy as np
 
 from repro.serve import (AsyncClusterEngine, ClusterRequest,
-                         LocalClusterEngine, MetricsRegistry, Tracer)
+                         LocalClusterEngine, MetricsRegistry)
 from repro.serve.telemetry import pool_label
-from repro.serve.tracing import TRACE_SCHEMA
 from .common import get_graph, emit
 
 TICK_COSTS_SCHEMA = "repro.bench.tick_costs/v1"
@@ -87,12 +78,10 @@ def _request_stream(graph, rng, n_requests: int, hot_seeds: int = 16,
 
 def _run_lane(graph, backend: str, n_requests: int, mean_gap_s: float,
               deadline_ms: float, batch_slots: int, caps: dict,
-              seed: int = 0, tracer=None, telemetry=None,
-              cost_table=None, stream_kw: dict = None) -> dict:
+              seed: int = 0, cost_table=None,
+              stream_kw: dict = None) -> dict:
     """Play one Poisson-arrival stream at a fresh scheduler; returns the
-    latency/miss summary for the BENCH_serve.json artifact.  With a
-    ``tracer`` the summary also carries per-request phase attribution,
-    Chrome trace events, and the telemetry postmortems."""
+    latency/miss summary for the BENCH_serve.json artifact."""
     rng = np.random.default_rng(seed)
     reqs = _request_stream(graph, rng, n_requests, **(stream_kw or {}))
     gaps = rng.exponential(mean_gap_s, size=n_requests)
@@ -105,9 +94,8 @@ def _run_lane(graph, backend: str, n_requests: int, mean_gap_s: float,
     t0 = time.perf_counter()
     engine.warmup([ClusterRequest(seed=0, alpha=0.05, eps=1e-4)],
                   max_bucket=1)
-    telem = telemetry if telemetry is not None else MetricsRegistry()
     sched = AsyncClusterEngine(engine, max_queue=4 * n_requests,
-                               tracer=tracer, telemetry=telem,
+                               telemetry=MetricsRegistry(),
                                cost_table=cost_table)
     with sched:
         sched.submit(ClusterRequest(seed=int(reqs[0].seed), alpha=0.05,
@@ -142,46 +130,7 @@ def _run_lane(graph, backend: str, n_requests: int, mean_gap_s: float,
         cache_hit_rate=hits / n_requests,
         status_syncs=engine.stats["status_syncs"],
     )
-    if tracer is not None:
-        recs = []
-        for f, r in zip(futs, results):
-            s = f.trace.summary()
-            s["deadline_missed"] = bool(r.deadline_missed)
-            # coverage against the *scheduler-measured* wall latency, the
-            # number the artifact reports (the root span tracks it to µs)
-            if f.latency_ms:
-                s["coverage"] = min(1.0, sum(s["phases_ms"].values())
-                                    / f.latency_ms)
-            recs.append(s)
-        out["requests"] = recs
-        covs = [s["coverage"] for s in recs if s["coverage"] is not None]
-        out["coverage_min"] = min(covs) if covs else None
-        out["coverage_mean"] = (sum(covs) / len(covs)) if covs else None
-        out["events"] = tracer.chrome_trace()
-        out["spans_dropped"] = tracer.dropped
-        out["postmortems"] = telem.postmortems()
     return out
-
-
-def _purity_probe(graph, batch_slots: int, caps: dict, n: int = 8) -> dict:
-    """Deterministic traced-vs-untraced comparison (guarantee #8): the same
-    request list through two fresh engines, one flight-recorded, one not —
-    every result field must agree bitwise.  Single-threaded and deadline-
-    free so the comparison is exact, not timing-dependent."""
-    rng = np.random.default_rng(7)
-    seeds = rng.choice(np.flatnonzero(np.asarray(graph.deg) > 0), size=n)
-    reqs = [ClusterRequest(seed=int(s), alpha=0.05, eps=1e-4) for s in seeds]
-    traced = LocalClusterEngine(graph, batch_slots=batch_slots,
-                                tracer=Tracer(), **caps).run(reqs)
-    plain = LocalClusterEngine(graph, batch_slots=batch_slots,
-                               **caps).run(reqs)
-    identical = all(
-        a.conductance == b.conductance and a.size == b.size
-        and a.volume == b.volume and a.support == b.support
-        and a.pushes == b.pushes and a.iterations == b.iterations
-        and np.array_equal(a.cluster, b.cluster)
-        for a, b in zip(traced, plain))
-    return dict(n_requests=n, bit_identical=identical)
 
 
 def _smoke_config() -> dict:
@@ -245,32 +194,19 @@ def characterize(smoke: bool = False,
     return doc
 
 
-def run(smoke: bool = False, trace: bool = False,
-        requests: int = None) -> dict:
+def run(smoke: bool = False, requests: int = None) -> dict:
     cfg = _smoke_config() if smoke else _full_config()
     if requests is not None:
         cfg["n_requests"] = requests
     graph = get_graph(cfg["name"])
     cost_table = TICK_COSTS_PATH if os.path.exists(TICK_COSTS_PATH) else None
     artifact = dict(graph=cfg["name"], smoke=smoke, lanes={})
-    traced_lanes = {}
     for backend in ("dense", "sparse"):
-        tracer = Tracer(capacity=1 << 16) if trace else None
-        telemetry = MetricsRegistry() if trace else None
         lane = _run_lane(graph, backend, cfg["n_requests"],
                          cfg["mean_gap_s"], cfg["deadline_ms"],
                          batch_slots=cfg["batch_slots"], caps=cfg["caps"],
-                         tracer=tracer, telemetry=telemetry,
                          cost_table=cost_table,
                          stream_kw=cfg.get("lane_streams", {}).get(backend))
-        if trace:
-            # the trace payload goes to BENCH_trace.json, not BENCH_serve
-            traced_lanes[backend] = {
-                k: lane.pop(k) for k in ("requests", "events", "postmortems",
-                                         "coverage_min", "coverage_mean",
-                                         "spans_dropped")}
-            traced_lanes[backend]["deadline_miss_rate"] = \
-                lane["deadline_miss_rate"]
         artifact["lanes"][backend] = lane
         emit(f"serve/{cfg['name']}/{backend}_poisson_B={cfg['n_requests']}",
              lane["p50_ms"] * 1e3,
@@ -282,23 +218,6 @@ def run(smoke: bool = False, trace: bool = False,
              lane["warmup_ms"] * 1e3,
              f"aot_compiles={lane['aot_compiles']};"
              f"aot_compile_s={lane['aot_compile_s']:.2f}")
-    if trace:
-        # one Perfetto-loadable event stream: lanes separated by pid
-        events = []
-        for pid, (backend, tl) in enumerate(traced_lanes.items()):
-            for ev in tl.pop("events"):
-                events.append(dict(ev, pid=pid))
-        trace_artifact = dict(
-            schema=TRACE_SCHEMA, suite="serve_trace", smoke=smoke,
-            generated_unix=time.time(), graph=cfg["name"],
-            deadline_ms=cfg["deadline_ms"],
-            purity=_purity_probe(graph, cfg["batch_slots"], cfg["caps"]),
-            lanes=traced_lanes,
-            traceEvents=events)
-        with open("BENCH_trace.json", "w") as f:
-            json.dump(trace_artifact, f, indent=2, sort_keys=True)
-        print("wrote BENCH_trace.json", flush=True)
-        artifact["trace_artifact"] = "BENCH_trace.json"
     return artifact
 
 
@@ -306,8 +225,6 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--trace", action="store_true",
-                    help="flight-record every request; write BENCH_trace.json")
     ap.add_argument("--requests", type=int, default=None,
                     help="override the stream length (default: 256 smoke / "
                          "64 full)")
@@ -319,5 +236,5 @@ if __name__ == "__main__":
     if args.characterize:
         print(json.dumps(characterize(smoke=args.smoke), indent=2))
     else:
-        print(json.dumps(run(smoke=args.smoke, trace=args.trace,
-                             requests=args.requests), indent=2))
+        print(json.dumps(run(smoke=args.smoke, requests=args.requests),
+                         indent=2))

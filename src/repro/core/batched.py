@@ -67,7 +67,7 @@ __all__ = [
     "rounds_remaining_hint", "hk_rounds_remaining",
     "LaneKernels", "dense_lane_kernels", "STATUS_ROWS",
     "STATUS_FINISHED", "STATUS_OVERFLOW", "STATUS_FRONTIER",
-    "STATUS_ITER", "STATUS_PUSHES", "STATUS_EXCHANGED",
+    "STATUS_ITER", "STATUS_PUSHES", "STATUS_EXCHANGED", "STATUS_EDGES",
 ]
 
 
@@ -444,31 +444,37 @@ def batched_cluster(graph: CSRGraph, seeds, eps=1e-6, alpha=0.01,
 # ------------------------------------------- executable-shaped lane kernels
 # The serving engine (serve/cluster_engine.py) steps resident lane pools
 # through exactly the round functions above, but needs them packaged as
-# *executables*: fixed-signature jits it can AOT-lower (.lower().compile())
-# per pool shape, with the lane state donated so a tick updates the pool
-# buffers in place.  These factories are that packaging — one LaneKernels
-# bundle per (n, method, statics, caps, rounds, backend) shape, lru_cached
-# so every engine instance (and every pool re-creation after LRU eviction)
-# shares one set of jit objects process-wide.
+# *executables*: fixed-signature functions it can jit under the pool's name
+# and AOT-lower (.lower().compile()) per pool shape, with the lane state
+# donated so a tick updates the pool buffers in place (serve/aot.py does
+# both).  These factories are that packaging — one LaneKernels bundle per
+# (n, method, statics, caps, rounds, backend) shape, lru_cached so every
+# engine instance (and every pool re-creation after LRU eviction) shares one
+# set of functions process-wide.
 
 # Row indices of the stacked int32[STATUS_ROWS, B] per-tick status readback
 # (LaneKernels.status): ONE device→host transfer carries every observable
 # the engine's harvest/scheduler path needs — finished & overflow flags,
-# frontier occupancy, iteration counter, push count, and (dist lanes only)
-# exchanged-pair count.  Results never depend on these being fresh; harvest
-# correctness does, so the engine pulls them once per tick, post-step.
+# frontier occupancy, iteration counter, push count, (dist lanes only)
+# exchanged-pair count, and the lane's running edge work (Σ expanded edges,
+# the numerator of the tick's edge-slot use).  Results never depend on these
+# being fresh; harvest correctness does, so the engine pulls them once per
+# tick, post-step.
 (STATUS_FINISHED, STATUS_OVERFLOW, STATUS_FRONTIER,
- STATUS_ITER, STATUS_PUSHES, STATUS_EXCHANGED) = range(6)
-STATUS_ROWS = 6
+ STATUS_ITER, STATUS_PUSHES, STATUS_EXCHANGED, STATUS_EDGES) = range(7)
+STATUS_ROWS = 7
 
 
 class LaneKernels(NamedTuple):
-    """Fixed-signature tick kernels for one lane-pool shape.
+    """Fixed-signature tick kernels for one lane-pool shape, as plain
+    functions: :func:`repro.serve.aot.compile_lane_executables` jits each
+    under the pool's executable name and donates the state of ``inject``
+    and ``step``.
 
     ``init(seeds[B]) → state`` (vmapped placeholder build);
-    ``inject(state, lane, seed) → state`` (donates ``state``);
-    ``step(graph, state, eps[B], alpha[B], active[B]) → state`` (donates
-    ``state``; ``alpha`` is ignored by HK-PR but kept in the signature so
+    ``inject(state, lane, seed) → state`` (``state`` donated);
+    ``step(graph, state, eps[B], alpha[B], active[B]) → state`` (``state``
+    donated; ``alpha`` is ignored by HK-PR but kept in the signature so
     every pool shares one calling convention);
     ``status(state) → int32[STATUS_ROWS, B]`` (the coalesced readback);
     ``sweep(graph, state, lane) → (order, meta_i32[4], φ)`` — the
@@ -514,16 +520,13 @@ def dense_lane_kernels(n: int, method: str, statics: tuple, cap_f: int,
     else:
         raise ValueError(f"unknown method: {method!r}")
 
-    @jax.jit
     def init(seeds):
         return jax.vmap(seed_init)(seeds)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
     def inject(state, lane, seed):
         return jax.tree.map(lambda buf, v: buf.at[lane].set(v),
                             state, seed_init(seed))
 
-    @functools.partial(jax.jit, donate_argnums=(1,))
     def step(graph, state, eps, alpha, active):
         def one(s, e, a, act):
             def cond(c):
@@ -539,7 +542,6 @@ def dense_lane_kernels(n: int, method: str, statics: tuple, cap_f: int,
             return s2
         return jax.vmap(one)(state, eps, alpha, active)
 
-    @jax.jit
     def status(state):
         fc = state.frontier.count.astype(jnp.int32)
         fin = ((fc == 0) | state.overflow | done_of(state)
@@ -548,9 +550,9 @@ def dense_lane_kernels(n: int, method: str, statics: tuple, cap_f: int,
                           state.overflow.astype(jnp.int32), fc,
                           iter_of(state).astype(jnp.int32),
                           state.pushes.astype(jnp.int32),
-                          jnp.zeros_like(fc)])
+                          jnp.zeros_like(fc),
+                          state.edge_work.astype(jnp.int32)])
 
-    @jax.jit
     def sweep(graph, state, lane):
         sw = sweep_cut_dense(graph, state.p[lane], cap_n, sweep_cap_e,
                              backend)

@@ -380,16 +380,13 @@ def sparse_lane_kernels(n: int, statics: tuple, cap_f: int, cap_v: int,
     optimized, _beta = statics
     seed_init = lambda s: pr_nibble_sparse_init(s, n, cap_f, cap_v)
 
-    @jax.jit
     def init(seeds):
         return jax.vmap(seed_init)(seeds)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
     def inject(state, lane, seed):
         return jax.tree.map(lambda buf, v: buf.at[lane].set(v),
                             state, seed_init(seed))
 
-    @functools.partial(jax.jit, donate_argnums=(1,))
     def step(graph, state, eps, alpha, active):
         def one(s, e, a, act):
             def cond(c):
@@ -407,7 +404,6 @@ def sparse_lane_kernels(n: int, statics: tuple, cap_f: int, cap_v: int,
             return s2
         return jax.vmap(one)(state, eps, alpha, active)
 
-    @jax.jit
     def status(state):
         fc = state.frontier.count.astype(jnp.int32)
         fin = (fc == 0) | state.overflow | (state.t >= 10_000)
@@ -415,9 +411,9 @@ def sparse_lane_kernels(n: int, statics: tuple, cap_f: int, cap_v: int,
                           state.overflow.astype(jnp.int32), fc,
                           state.t.astype(jnp.int32),
                           state.pushes.astype(jnp.int32),
-                          jnp.zeros_like(fc)])
+                          jnp.zeros_like(fc),
+                          state.edge_work.astype(jnp.int32)])
 
-    @jax.jit
     def sweep(graph, state, lane):
         sw = sweep_cut_sparse(graph, state.p.ids[lane], state.p.vals[lane],
                               state.p.count[lane], sweep_cap_e,

@@ -63,6 +63,7 @@ class _Shard(NamedTuple):
     exchanged: jnp.ndarray   # replicated int32 — cross-shard routed slots
 
 
+@jax.named_scope("expand")
 def _local_expand(indptr, indices, deg, f_loc, f_valid, cap_e, rows_per,
                   backend="xla"):
     """Expand a local frontier (local ids) against the local CSR slab.
@@ -90,6 +91,7 @@ def _local_expand(indptr, indices, deg, f_loc, f_valid, cap_e, rows_per,
 _GLOBAL_SENTINEL = 2 ** 30   # "nowhere" destination for masked slots
 
 
+@jax.named_scope("frontier")
 def local_frontier_pack(r_loc, deg, eps, rows_per: int, cap_f: int,
                         backend: str = "xla"):
     """Pack local ids with ``r >= d*eps`` (deg > 0) ascending into ``cap_f``

@@ -19,7 +19,12 @@ and surfaced as a flag; drivers retry at the next power-of-two bucket
 (`bucketed recompilation` — the static-shape analogue of queue growth, at most
 O(log) recompiles per graph).
 
-All functions are pure jnp and usable under jit / vmap / shard_map.
+All functions are pure jnp and usable under jit / vmap / shard_map.  Each
+round phase runs under a ``jax.named_scope`` — ``expand`` (frontier → edge
+list), ``scatter`` (accumulating into p and r) and ``frontier`` (threshold
+test and compaction) — so every HLO operation of a compiled round carries
+its phase in its ``op_name`` metadata (serve/aot.py maps profiled ops back
+to it).  Scopes are metadata only: they change no computation.
 """
 from __future__ import annotations
 
@@ -86,6 +91,7 @@ def seed_set(vs: jnp.ndarray, count, n: int, cap_f: int) -> Frontier:
                     overflow=jnp.asarray(k > cap_f))
 
 
+@jax.named_scope("expand")
 def expand(graph: CSRGraph, frontier: Frontier, cap_e: int,
            backend: str = "xla") -> EdgeBatch:
     """Enumerate all edges incident to the frontier into ``cap_e`` slots.
@@ -116,6 +122,7 @@ def expand(graph: CSRGraph, frontier: Frontier, cap_e: int,
                      overflow=total > cap_e)
 
 
+@jax.named_scope("frontier")
 def pack_unique(cands: jnp.ndarray, keep: jnp.ndarray, n: int,
                 cap_out: int, backend: str = "xla") -> Frontier:
     """Filter + dedupe candidate vertex ids into a fresh frontier.
@@ -138,6 +145,7 @@ def pack_unique(cands: jnp.ndarray, keep: jnp.ndarray, n: int,
                     overflow=count > cap_out)
 
 
+@jax.named_scope("scatter")
 def scatter_add_dense(vec: jnp.ndarray, idx: jnp.ndarray, vals: jnp.ndarray,
                       valid: jnp.ndarray, backend: str = "xla") -> jnp.ndarray:
     """fetchAdd → scatter-add: accumulate ``vals`` at ``idx`` (masked).
@@ -149,6 +157,7 @@ def scatter_add_dense(vec: jnp.ndarray, idx: jnp.ndarray, vals: jnp.ndarray,
     return ops.scatter_add(vec, idx, vals, valid, backend=backend)
 
 
+@jax.named_scope("scatter")
 def scatter_set_dense(vec: jnp.ndarray, idx: jnp.ndarray, vals,
                       valid: jnp.ndarray) -> jnp.ndarray:
     """Masked ``vec.at[idx].set(vals)`` with the shared drop-sentinel

@@ -107,12 +107,14 @@ def hk_pr_round(graph: CSRGraph, s: HKPRState, N: int, eps, t: float,
                                backend=backend)
 
     # frontier for level j+1: r'[v] ≥ eᵗ ε d(v) / (2N ψ_{j+1})
-    thresh_coef = scale * eps / (2.0 * N * psi_table[jnp.minimum(s.j + 1, N)])
-    cands = eb.dst
-    csafe = jnp.minimum(cands, n - 1)
-    keep = eb.valid & (deg[csafe] > 0) & \
-        (r_next[csafe] >= deg[csafe] * thresh_coef)
-    nf = pack_unique(cands, keep, n, s.frontier.cap, backend=backend)
+    with jax.named_scope("frontier"):
+        thresh_coef = scale * eps / (2.0 * N *
+                                     psi_table[jnp.minimum(s.j + 1, N)])
+        cands = eb.dst
+        csafe = jnp.minimum(cands, n - 1)
+        keep = eb.valid & (deg[csafe] > 0) & \
+            (r_next[csafe] >= deg[csafe] * thresh_coef)
+        nf = pack_unique(cands, keep, n, s.frontier.cap, backend=backend)
 
     return HKPRState(
         p=jnp.where(last, p_last, p_new),

@@ -147,11 +147,12 @@ def pr_nibble_round(graph: CSRGraph, s: PRNibbleState, eps, alpha,
     r_new = scatter_add_dense(r_new, eb.dst, contrib, eb.valid,
                               backend=backend)
 
-    cands = jnp.concatenate([all_fids, eb.dst])
-    cvalid = jnp.concatenate([all_fvalid, eb.valid])
-    csafe = jnp.minimum(cands, n - 1)
-    keep = cvalid & (deg[csafe] > 0) & (r_new[csafe] >= deg[csafe] * eps)
-    nf = pack_unique(cands, keep, n, s.frontier.cap, backend=backend)
+    with jax.named_scope("frontier"):
+        cands = jnp.concatenate([all_fids, eb.dst])
+        cvalid = jnp.concatenate([all_fvalid, eb.valid])
+        csafe = jnp.minimum(cands, n - 1)
+        keep = cvalid & (deg[csafe] > 0) & (r_new[csafe] >= deg[csafe] * eps)
+        nf = pack_unique(cands, keep, n, s.frontier.cap, backend=backend)
 
     return PRNibbleState(p=p_new, r=r_new, frontier=nf, t=s.t + 1,
                          pushes=s.pushes + f.count,

@@ -56,6 +56,7 @@ class PRNibbleSparseState(NamedTuple):
     frontier: Frontier
     t: jnp.ndarray
     pushes: jnp.ndarray
+    edge_work: jnp.ndarray   # int32 — Σ expanded edges (eb.total) per round
     overflow: jnp.ndarray
 
 
@@ -72,6 +73,7 @@ def pr_nibble_sparse_init(x, n: int, cap_f: int, cap_v: int) -> PRNibbleSparseSt
                                frontier=singleton(x, n, cap_f),
                                t=jnp.asarray(0, jnp.int32),
                                pushes=jnp.asarray(0, jnp.int32),
+                               edge_work=jnp.asarray(0, jnp.int32),
                                overflow=jnp.asarray(False))
 
 
@@ -114,15 +116,17 @@ def pr_nibble_sparse_round(graph: CSRGraph, s: PRNibbleSparseState, eps, alpha,
     r_new = sv_merge_add(r_new, eb.dst, share[eb.slot], eb.valid, n,
                          backend=backend)
 
-    cands = jnp.concatenate([fids, eb.dst])
-    cvalid = jnp.concatenate([fvalid, eb.valid])
-    csafe = jnp.minimum(cands, n - 1)
-    r_cand = sv_lookup(r_new, cands, n)
-    keep = cvalid & (deg[csafe] > 0) & (r_cand >= deg[csafe] * eps)
-    nf = pack_unique(cands, keep, n, f.cap, backend=backend)
+    with jax.named_scope("frontier"):
+        cands = jnp.concatenate([fids, eb.dst])
+        cvalid = jnp.concatenate([fvalid, eb.valid])
+        csafe = jnp.minimum(cands, n - 1)
+        r_cand = sv_lookup(r_new, cands, n)
+        keep = cvalid & (deg[csafe] > 0) & (r_cand >= deg[csafe] * eps)
+        nf = pack_unique(cands, keep, n, f.cap, backend=backend)
 
     return PRNibbleSparseState(p=p_new, r=r_new, frontier=nf, t=s.t + 1,
                                pushes=s.pushes + f.count,
+                               edge_work=s.edge_work + eb.total,
                                overflow=(s.overflow | nf.overflow |
                                          eb.overflow | p_new.overflow |
                                          r_new.overflow))

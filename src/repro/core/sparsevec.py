@@ -70,6 +70,7 @@ def sv_lookup(sv: SparseVec, queries: jnp.ndarray, n: int) -> jnp.ndarray:
     return jnp.where(hit, sv.vals[pos], 0.0)
 
 
+@jax.named_scope("scatter")
 def sv_update_existing(sv: SparseVec, ids, new_vals, valid) -> SparseVec:
     """Overwrite values of keys already present (no structural change)."""
     pos = jnp.clip(jnp.searchsorted(sv.ids, ids), 0, sv.cap - 1)
@@ -77,6 +78,7 @@ def sv_update_existing(sv: SparseVec, ids, new_vals, valid) -> SparseVec:
     return sv._replace(vals=scatter_set_dense(sv.vals, pos, new_vals, hit))
 
 
+@jax.named_scope("scatter")
 def sv_merge_add(sv: SparseVec, upd_ids, upd_vals, upd_valid, n: int,
                  backend: str = "xla") -> SparseVec:
     """`r[w] += delta` for a batch of updates — the fetchAdd batch.

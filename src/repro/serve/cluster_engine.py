@@ -28,14 +28,14 @@ status, harvest-gather sweep — is ``jit(...).lower(...).compile()``'d once
 per pool key (eagerly via :meth:`LocalClusterEngine.warmup`, else at first
 pool creation), with the lane state **donated** on inject/step so pool
 buffers update in place.  A tick pays exactly **one** device→host sync: the
-stacked int32[6, B] status readback (finished / overflow / frontier / iters
-/ pushes / exchanged), mirrored host-side and consumed by harvest, the
-finalize counters, the scheduler's pending-rounds hints, and trace
-annotations alike.  Harvest copies a finished lane's *support* (order
-buffer + 4 counters + φ), never pool state.  In front of it all sits a
-versioned seed→result LRU (serve/result_cache.py): a repeated query resolves
-at submit in O(1), keyed on the handle's graph version so edge mutations
-invalidate wholesale.  None of this changes answers — AOT lowering,
+stacked int32[7, B] status readback (finished / overflow / frontier / iters
+/ pushes / exchanged / edge work), mirrored host-side and consumed by
+harvest, the finalize counters, the tick's edge-slot counters, the
+scheduler's pending-rounds hints, and trace annotations alike.  Harvest
+copies a finished lane's *support* (order buffer + 4 counters + φ), never
+pool state.  In front of it all sits a versioned seed→result LRU
+(serve/result_cache.py): a repeated query resolves at submit in O(1), keyed
+on the handle's graph version so edge mutations invalidate wholesale.  None of this changes answers — AOT lowering,
 donation, coalesced readbacks, and caching move bytes and compile time,
 never values (docs/algorithms.md, guarantee #9).
 
@@ -94,8 +94,10 @@ occupancy) move through traced values.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
+import threading
 import time
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Tuple
@@ -110,15 +112,16 @@ from repro.core import ops as core_ops
 from repro.core.batched_dist import dist_lane_kernels
 from repro.core.pr_nibble import MAX_ITERS
 from repro.core.sweep import sweep_cut_dense, sweep_cut_sparse
-from repro.core.batched import (STATUS_EXCHANGED, STATUS_FINISHED,
-                                STATUS_FRONTIER, STATUS_ITER, STATUS_OVERFLOW,
-                                STATUS_PUSHES, dense_lane_kernels,
-                                hk_rounds_remaining, rounds_remaining_hint)
+from repro.core.batched import (STATUS_EDGES, STATUS_EXCHANGED,
+                                STATUS_FINISHED, STATUS_FRONTIER, STATUS_ITER,
+                                STATUS_OVERFLOW, STATUS_PUSHES,
+                                dense_lane_kernels, hk_rounds_remaining,
+                                rounds_remaining_hint)
 from repro.core.batched_sparse import pick_backend, sparse_lane_kernels
 from repro.serve.aot import ExecutableCache, compile_lane_executables
 from repro.serve.result_cache import ResultCache, result_key
 from repro.serve.telemetry import EMA, pool_label
-from repro.serve.tracing import RequestTrace, Tracer
+from repro.serve.tracing import RequestTrace, Tracer, watch_compiles
 
 __all__ = ["ClusterRequest", "ClusterResult", "LocalClusterEngine",
            "UnknownTicket"]
@@ -180,15 +183,15 @@ class ClusterResult:
 # per topology); only their coalesced status readback lives here.
 
 @jax.jit
-def _dist_status(front, t, pushes, overflow, exchanged):
-    """Stacked int32[6, B] status readback for dist lanes — the replicated
+def _dist_status(front, t, pushes, overflow, exchanged, edge_work):
+    """Stacked int32[7, B] status readback for dist lanes — the replicated
     per-lane scalars of DistLaneState, in the STATUS_* row order of
     repro.core.batched, so one transfer serves harvest, the scheduler's
     pending-rounds hints, and the trace annotations."""
     i32 = lambda x: x.astype(jnp.int32)
     fin = (front == 0) | overflow | (t >= MAX_ITERS)
     return jnp.stack([i32(fin), i32(overflow), i32(front), i32(t),
-                      i32(pushes), i32(exchanged)])
+                      i32(pushes), i32(exchanged), i32(edge_work)])
 
 
 # ----------------------------------------------------------------- lane pool
@@ -217,6 +220,8 @@ class _Pool:
         self.cap_v = caps["cap_v"]
         B = engine.batch_slots
         # lanes start inactive; injected states overwrite these placeholders
+        # (host seeds: building them with jnp would compile after warmup)
+        seeds = np.zeros(B, np.int32)
         if backend == "dist":
             pg = engine.handle.partitioned()
             mesh = engine.handle.require_mesh()
@@ -227,13 +232,16 @@ class _Pool:
                                   self.cap_f, self.cap_e, self.cap_x,
                                   optimized, ops_backend)
             self.exec = None    # dist pools step through the shard_map jits
-            self.state = self._dist_init(jnp.zeros((B,), jnp.int32))
+            self.state = self._dist_init(seeds)
+            # each shard expands into its own cap_e edge workspace
+            self.edge_slots_per_round = self.cap_e * topo[1]
         else:
             # AOT executables from the engine's cache: a re-created pool
             # (after LRU eviction) or a ladder hop re-uses the compiled
             # programs — pool construction never re-traces after warmup
             self.exec = engine._executables_for(key)
-            self.state = self.exec.init(jnp.zeros((B,), jnp.int32))
+            self.state = self.exec.init(seeds)
+            self.edge_slots_per_round = self.cap_e
         self.eps = np.zeros(B, np.float32)
         self.alpha = np.zeros(B, np.float32)
         self.lane: List[Optional[Tuple[int, ClusterRequest]]] = [None] * B
@@ -324,8 +332,8 @@ class _Pool:
             self.lane[i] = (idx, req)
             self.eps[i] = req.eps
             self.alpha[i] = req.alpha
-            lane = jnp.asarray(i, jnp.int32)
-            seed = jnp.asarray(req.seed, jnp.int32)
+            # host scalars: a jnp conversion would compile after warmup
+            lane, seed = np.int32(i), np.int32(req.seed)
             if self.backend == "dist":
                 self.state = self._dist_inject(self.state, lane, seed)
             else:
@@ -334,10 +342,11 @@ class _Pool:
             if self._status_host is not None:
                 # keep the host status mirror truthful for lanes injected
                 # after the last pull: a fresh lane is exactly (unfinished,
-                # no overflow, singleton frontier, 0 iters, 0 pushes) — so
-                # a force-finalize or scheduler hint between now and the
-                # next harvest reads correct values without a sync
-                self._status_host[:, i] = (0, 0, 1, 0, 0, 0)
+                # no overflow, singleton frontier, 0 iters, 0 pushes, no
+                # edge work) — so a force-finalize, scheduler hint or the
+                # next harvest's edge delta reads correct values without a
+                # sync
+                self._status_host[:, i] = (0, 0, 1, 0, 0, 0, 0)
             self.engine.stats["injections"] += 1
             rt = self.engine._rt.get(idx)
             if rt is not None:
@@ -362,20 +371,26 @@ class _Pool:
                 jnp.asarray(self.alpha), jnp.asarray(active))
         self.engine.stats["steps"] += 1
 
+    def _region(self, name: str):
+        """A tracer region (span + device annotation) when traced."""
+        tr = self.engine.tracer
+        return contextlib.nullcontext() if tr is None else tr.region(name)
+
     def _pull_status(self) -> np.ndarray:
-        """The tick's ONE device→host sync: the stacked int32[6, B] status
-        readback (finished/overflow/frontier/iters/pushes/exchanged), cached
-        on the pool for everything downstream — harvest decisions, finalize
-        counters, scheduler hints, trace annotations."""
+        """The tick's ONE device→host sync: the stacked int32[7, B] status
+        readback (finished/overflow/frontier/iters/pushes/exchanged/edge
+        work), cached on the pool for everything downstream — harvest
+        decisions, finalize counters, scheduler hints, trace annotations."""
         st = self.state
         if self.backend == "dist":
             dev = _dist_status(st.front, st.t, st.pushes, st.overflow,
-                               st.exchanged)
+                               st.exchanged, st.edge_work)
         else:
             dev = self.exec.status(st)
         # np.array (not asarray): the mirror must be writable — refill
         # patches freshly injected lanes' rows host-side between pulls
-        self._status_host = np.array(dev)
+        with self._region("status_wait"):
+            self._status_host = np.array(dev)
         self.engine.stats["status_syncs"] += 1
         return self._status_host
 
@@ -386,10 +401,45 @@ class _Pool:
             return self._pull_status()
         return self._status_host
 
-    def harvest(self) -> None:
-        if not any(l is not None for l in self.lane):
-            return
+    def _tick_work(self, prev: Optional[np.ndarray],
+                   sh: np.ndarray) -> Tuple[np.ndarray, int]:
+        """Per-lane edges expanded since the previous status pull, and the
+        tick's round count, from two host mirrors (no device access).
+
+        A lane's edges are its ``edge_work`` delta, capped at its rounds ×
+        the pool's edge slots per round: a round whose expansion overflowed
+        counts its true ``total`` (it reruns a bucket up), and the cap keeps
+        the tick's edge-slot use at or under 100 %.  The rounds are the
+        largest per-lane iteration delta — the vmapped loop's trip count.
+        Before a pool's first pull every lane is fresh or an untouched
+        placeholder, so the previous mirror is zeros."""
+        if prev is None:
+            prev = np.zeros_like(sh)
+        rounds = (sh[STATUS_ITER] - prev[STATUS_ITER]).astype(np.int64)
+        # edge_work is a running int32: a modular difference survives a wrap
+        edges = (sh[STATUS_EDGES].astype(np.uint32)
+                 - prev[STATUS_EDGES].astype(np.uint32)).astype(np.int64)
+        edges = np.minimum(edges, rounds * self.edge_slots_per_round)
+        return edges, int(rounds.max(initial=0))
+
+    def harvest(self) -> Dict[str, int]:
+        """Pull the tick's status, account its edge work, annotate traced
+        requests, and finalize or promote every finished lane.  Returns the
+        tick's work counters (``edges``, ``edge_slots``, ``rounds``,
+        ``lanes_active``; zeros when no lane was active)."""
+        active = [slot is not None for slot in self.lane]
+        if not any(active):
+            return dict(edges=0, edge_slots=0, rounds=0, lanes_active=0)
+        prev = self._status_host
         sh = self._pull_status()
+        edges, rounds = self._tick_work(prev, sh)
+        work = dict(edges=int(edges.sum()),
+                    edge_slots=len(self.lane) * self.edge_slots_per_round
+                    * rounds,
+                    rounds=rounds, lanes_active=sum(active))
+        stats = self.engine.stats
+        stats["edges_touched"] += work["edges"]
+        stats["edge_slots"] += work["edge_slots"]
         finished = sh[STATUS_FINISHED].astype(bool)
         ovf = sh[STATUS_OVERFLOW].astype(bool)
         count = sh[STATUS_FRONTIER]
@@ -403,6 +453,7 @@ class _Pool:
                 if rt is not None:
                     obs = dict(frontier=int(count[i]),
                                pushes=int(sh[STATUS_PUSHES][i]),
+                               edges=int(edges[i]),
                                overflow=bool(ovf[i]),
                                finished=bool(finished[i]))
                     if self.backend == "dist":
@@ -424,6 +475,7 @@ class _Pool:
                          overflow=bool(ovf[i]))
                 rt.phase("sweep", bucket=self.bucket)
             self.engine._complete(idx, self._finalize(i, req, bool(ovf[i])))
+        return work
 
     def force_finalize(self, i: int) -> ClusterResult:
         """Harvest lane ``i`` *now*, finished or not: sweep whatever
@@ -452,17 +504,20 @@ class _Pool:
             # support out of the pool and sweep it on-device — only the
             # order buffer, 4 counters, and φ cross to the host, never the
             # pool state.
-            order, meta, phi = self.exec.sweep(eng.graph, self.state,
-                                               jnp.asarray(i, jnp.int32))
-            meta = np.asarray(meta)   # [best_size, best_volume, nnz, ovf]
+            with self._region("sweep_dispatch"):
+                order, meta, phi = self.exec.sweep(eng.graph, self.state,
+                                                   np.int32(i))
+                meta = np.asarray(meta)   # [best_size, best_volume, nnz, ovf]
+                phi = np.asarray(phi)
+                order = np.asarray(order)
             sweep_ovf = bool(meta[3])
             exhausted = (cap_se >= max_cap_se
                          and (self.backend == "sparse" or cap_n >= n))
             if not sweep_ovf or exhausted:
                 size = int(meta[0])
-                conductance = float(np.asarray(phi))
+                conductance = float(phi)
                 volume, support = int(meta[1]), int(meta[2])
-                members = np.asarray(order)[:size].astype(np.int32)
+                members = order[:size].astype(np.int32)
                 overflowed = overflowed or sweep_ovf
         if size is None:
             # Sweep workspace too small at pool caps (rare), or a dist lane
@@ -609,13 +664,18 @@ class LocalClusterEngine:
             self.result_cache = ResultCache(int(result_cache))
         else:
             self.result_cache = None
+        # edges_touched / edge_slots: Σ edges the ticks' rounds expanded,
+        # and Σ edge slots they computed (B × cap_e × rounds a tick) — their
+        # ratio is the diffusion's edge-slot use.  backend_compiles: XLA
+        # backend compiles in this process while the engine lives.
         self.stats: Dict = dict(steps=0, injections=0, promotions=0,
                                 completed=0, pools_created=0,
                                 pools_evicted=0, partial_harvests=0,
                                 status_syncs=0, aot_compiles=0,
                                 aot_cache_hits=0, aot_compile_s=0.0,
                                 result_cache_hits=0, result_cache_misses=0,
-                                bucket_shapes=set())
+                                edges_touched=0, edge_slots=0,
+                                backend_compiles=0, bucket_shapes=set())
         self._results: Dict[int, ClusterResult] = {}
         self._next_idx = 0
         self.tracer = tracer
@@ -623,6 +683,18 @@ class LocalClusterEngine:
         # ticket → RequestTrace for in-flight traced requests; traces are
         # finished and dropped at result pickup
         self._rt: Dict[int, RequestTrace] = {}
+        self._compile_lock = threading.Lock()
+        watch_compiles(self)
+
+    def on_compile(self, fun_name: str, seconds: float) -> None:
+        """Count one XLA backend compile of this process (called on the
+        compiling thread) and, when traced, record it as a ``compile``
+        event under the active scope."""
+        with self._compile_lock:
+            self.stats["backend_compiles"] += 1
+        tr = self.tracer
+        if tr is not None:
+            tr.compile_event(fun_name, seconds)
 
     @property
     def graph(self) -> CSRGraph:
@@ -664,7 +736,7 @@ class LocalClusterEngine:
         caps = self._pool_caps(key)
         n = self.handle.n
 
-        def build():
+        def build(name):
             if backend == "sparse":
                 kern = sparse_lane_kernels(
                     n, statics, caps["cap_f"], caps["cap_v"], caps["cap_e"],
@@ -675,7 +747,7 @@ class LocalClusterEngine:
                     caps["cap_n"], caps["sweep_cap_e"],
                     self.rounds_per_step, ops_backend)
             return compile_lane_executables(kern, self.graph,
-                                            self.batch_slots)
+                                            self.batch_slots, name)
 
         ex = self._exec_cache.get(key, build)
         cs = self._exec_cache.stats()
@@ -891,20 +963,27 @@ class LocalClusterEngine:
             pool.harvest()  # device→host sync: the measured time is honest
             dt = time.perf_counter() - t0
         else:
+            # the tick span ends with the tick's work counters: edges,
+            # edge_slots, rounds, lanes_active
             label = pool_label(key)
-            with tr.span("tick", cat="pool", pool=label,
-                         occupancy=pool.occupancy(), queued=len(pool.queue),
-                         cost_ema=pool.cost_ema) as tick_sid, \
-                    tr.scope(parent=tick_sid), \
-                    tr.device_span(f"tick:{label}"):
-                t0 = time.perf_counter()
-                with tr.span("refill", cat="pool", parent=tick_sid):
-                    pool.refill()
-                with tr.span("step", cat="pool", parent=tick_sid):
-                    pool.step()
-                with tr.span("harvest", cat="pool", parent=tick_sid):
-                    pool.harvest()
-                dt = time.perf_counter() - t0
+            tick_sid = tr.begin("tick", cat="pool", pool=label,
+                                occupancy=pool.occupancy(),
+                                queued=len(pool.queue),
+                                cost_ema=pool.cost_ema)
+            work = {}
+            try:
+                with tr.scope(parent=tick_sid), \
+                        tr.device_span(f"tick:{label}"):
+                    t0 = time.perf_counter()
+                    with tr.region("refill"):
+                        pool.refill()
+                    with tr.region("step"):
+                        pool.step()
+                    with tr.span("harvest", cat="pool", parent=tick_sid):
+                        work = pool.harvest()
+                    dt = time.perf_counter() - t0
+            finally:
+                tr.end(tick_sid, **work)
         pool.note_tick(dt)
         if key in self.pools:   # harvest may promote+evict this very pool
             self.pools.move_to_end(key)

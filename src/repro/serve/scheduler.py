@@ -471,5 +471,6 @@ class AsyncClusterEngine:
         for stat in ("promotions", "pools_evicted", "injections",
                      "completed", "partial_harvests", "steps",
                      "status_syncs", "aot_compiles", "aot_cache_hits",
-                     "result_cache_hits", "result_cache_misses"):
+                     "result_cache_hits", "result_cache_misses",
+                     "edges_touched", "edge_slots", "backend_compiles"):
             tm.set_gauge(f"engine/{stat}", self.engine.stats[stat])

@@ -11,11 +11,12 @@ tree per request across its full lifecycle
     submit → queued → admitted → injected → tick* → harvest → sweep
            → resolved | expired
 
-plus pool-scoped ``tick`` spans (refill/step/harvest children, occupancy and
-cost-EMA snapshots) and algorithm-level annotations threaded up from the
-batched drivers (per-tick frontier sizes, push counts, capacity-ladder
-bucket hops, overflow events, dist exchange volume — the paper-native work
-measures).
+plus pool-scoped ``tick`` spans (refill / step / harvest / status_wait /
+sweep_dispatch children; occupancy and cost-EMA snapshots at the start, the
+tick's edge work at the end), ``compile`` events naming every XLA backend
+compile, and algorithm-level annotations threaded up from the batched
+layers (per-tick frontier sizes, push counts, capacity-ladder bucket hops,
+overflow events, dist exchange volume — the paper-native work measures).
 
 Design rules (docs/algorithms.md, guarantee #8):
 
@@ -33,12 +34,12 @@ Design rules (docs/algorithms.md, guarantee #8):
     *phase accounting* (:class:`RequestTrace`) is kept separately in O(1)
     per request so latency attribution survives ring eviction.
 
-Exports: :meth:`Tracer.chrome_trace` renders Chrome trace-event JSON —
-load the file in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``;
-requests appear as one track per request id, pool ticks on track 0.
-:meth:`Tracer.device_span` optionally wraps pool ticks in
-``jax.profiler.TraceAnnotation`` so these host spans line up with device
-traces captured by ``jax.profiler.trace``.
+Timeline view: with ``Tracer(device_annotations=True)`` the engine's tick
+and its refill / step / status_wait / sweep_dispatch parts are also
+``jax.profiler.TraceAnnotation`` scopes (:meth:`Tracer.device_span`), so a
+profiler trace of the serving process (``jax.profiler.trace(dir,
+create_perfetto_trace=True)``) shows them on the device trace's clock,
+beside the device's executables, in Perfetto (https://ui.perfetto.dev).
 
 On deadline expiry the scheduler dumps the victim's span tree
 (:meth:`Tracer.request_tree`) into the telemetry snapshot as a bounded
@@ -49,11 +50,12 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Dict, List, Optional
 
 __all__ = ["Span", "Tracer", "RequestTrace", "annotate", "current_scope",
-           "TRACE_SCHEMA"]
+           "watch_compiles", "TRACE_SCHEMA"]
 
 TRACE_SCHEMA = "repro.serve.trace/v1"
 
@@ -120,6 +122,41 @@ def annotate(name: str, **attrs) -> None:
     tracer.event(name, cat="annotation", parent=parent, rid=rid, **attrs)
 
 
+# ----------------------------------------------------------- compile events
+# One jax.monitoring listener for the whole process, registered by the first
+# watch_compiles() call, fans every XLA backend compile out to the live
+# sinks.  Sinks are held weakly: a dropped engine stops receiving events and
+# nothing outlives it.
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_lock = threading.Lock()
+_compile_sinks: "weakref.WeakSet" = weakref.WeakSet()
+_compile_listening = False
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    with _compile_lock:
+        sinks = list(_compile_sinks)
+    for sink in sinks:
+        sink.on_compile(kw.get("fun_name", "?"), duration)
+
+
+def watch_compiles(sink) -> None:
+    """Call ``sink.on_compile(fun_name, seconds)`` for every XLA backend
+    compile in this process, on the compiling thread, while ``sink`` lives
+    (it is held weakly)."""
+    global _compile_listening
+    with _compile_lock:
+        _compile_sinks.add(sink)
+        if not _compile_listening:
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _compile_listening = True
+
+
 class Tracer:
     """Thread-safe bounded flight recorder of :class:`Span` records.
 
@@ -142,7 +179,6 @@ class Tracer:
         self._open: Dict[int, Span] = {}
         self._next_sid = 0
         self._next_rid = 0
-        self._epoch = _now()     # t=0 of every exported timestamp
 
     # -- span primitives -----------------------------------------------------
 
@@ -224,6 +260,26 @@ class Tracer:
             return contextlib.nullcontext()
         return TraceAnnotation(name)
 
+    def _scope_parent(self) -> Optional[int]:
+        """Parent sid of the active scope when it is this tracer's."""
+        top = current_scope()
+        return top[1] if top is not None and top[0] is self else None
+
+    @contextlib.contextmanager
+    def region(self, name: str, **attrs):
+        """``with tracer.region("step"): ...`` — a ``pool`` span under the
+        active scope (inside a tick: a child of the tick span) that is also
+        a :meth:`device_span` of the same name; yields the sid."""
+        with self.span(name, cat="pool", parent=self._scope_parent(),
+                       **attrs) as sid, self.device_span(name):
+            yield sid
+
+    def compile_event(self, fun_name: str, seconds: float) -> None:
+        """Record one XLA backend compile under the active scope (the tick
+        span when the compile happens inside a tick)."""
+        self.event("compile", cat="compile", parent=self._scope_parent(),
+                   fun_name=fun_name, seconds=seconds)
+
     # -- request lifecycle ---------------------------------------------------
 
     def request(self, **attrs) -> "RequestTrace":
@@ -269,28 +325,6 @@ class Tracer:
         return dict(schema=TRACE_SCHEMA, rid=rid, spans=len(spans),
                     truncated=truncated, dropped_ring_total=self.dropped,
                     tree=roots)
-
-    # -- export --------------------------------------------------------------
-
-    def chrome_trace(self) -> List[Dict[str, Any]]:
-        """Chrome trace-event list (Perfetto/chrome://tracing loadable):
-        complete events (ph "X") for spans, instants (ph "i") for events;
-        one tid per request, tid 0 for pool/driver scope."""
-        events: List[Dict[str, Any]] = []
-        for s in self.spans():
-            tid = 0 if s.rid is None else s.rid + 1
-            ts = (s.t0 - self._epoch) * 1e6
-            args = dict(s.attrs)
-            if s.rid is not None:
-                args["rid"] = s.rid
-            base = dict(name=s.name, cat=s.cat, pid=0, tid=tid, ts=ts,
-                        args=args)
-            if s.t1 is None or s.t1 == s.t0:
-                events.append(dict(base, ph="i", s="t"))
-            else:
-                events.append(dict(base, ph="X",
-                                   dur=(s.t1 - s.t0) * 1e6))
-        return events
 
 
 class RequestTrace:
@@ -390,8 +424,7 @@ class RequestTrace:
         return min(1.0, sum(self.phase_ms.values()) / total)
 
     def summary(self) -> Dict[str, Any]:
-        """JSON-able per-request attribution record (the BENCH_trace.json
-        ``requests`` section)."""
+        """JSON-able per-request attribution record."""
         return dict(rid=self.rid, latency_ms=self.latency_ms,
                     status=self.status, coverage=self.coverage(),
                     phases_ms={k: round(v, 6)
