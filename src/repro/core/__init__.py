@@ -8,7 +8,8 @@ capacity frontiers — jit/vmap/shard_map-ready.  Sequential references in
 """
 from . import ops
 from .frontier import (Frontier, EdgeBatch, singleton, expand, pack_unique,
-                       next_pow2, scatter_add_dense, scatter_set_dense)
+                       compact_sorted, next_pow2, scatter_add_dense,
+                       scatter_set_dense)
 from .sweep import SweepResult, sweep_cut, sweep_cut_dense, sweep_cut_sparse
 from .nibble import NibbleResult, nibble, nibble_fixedcap
 from .pr_nibble import PRNibbleResult, pr_nibble, pr_nibble_fixedcap
@@ -39,7 +40,8 @@ from . import seq
 
 __all__ = [
     "ops",
-    "Frontier", "EdgeBatch", "singleton", "expand", "pack_unique", "next_pow2",
+    "Frontier", "EdgeBatch", "singleton", "expand", "pack_unique",
+    "compact_sorted", "next_pow2",
     "scatter_add_dense", "scatter_set_dense",
     "SweepResult", "sweep_cut", "sweep_cut_dense", "sweep_cut_sparse",
     "NibbleResult", "nibble", "nibble_fixedcap",

@@ -58,7 +58,7 @@ from . import ops as _ops
 from .batched import (_bucketed_retry, _prep_batch, _CapLadder,
                       LaneKernels as _LaneKernels,
                       rounds_remaining_hint as _dense_rounds_remaining_hint)
-from .pr_nibble_sparse import pr_nibble_sparse_fixedcap
+from .pr_nibble_sparse import check_sparse_eps, pr_nibble_sparse_fixedcap
 from .sweep import sweep_cut_sparse
 
 __all__ = [
@@ -240,6 +240,7 @@ def batched_pr_nibble_sparse(graph: CSRGraph, seeds, eps=1e-7, alpha=0.01,
     """
     graph = _ops.local_csr(graph)   # any graph-like (GraphHandle ok)
     seeds, B, eps, alpha = _prep_batch(seeds, eps, alpha)
+    check_sparse_eps(eps)
     n = graph.n
     out = dict(p_ids=np.full((B, cap_v), n, np.int32),
                p_vals=np.zeros((B, cap_v), np.float32),
@@ -282,6 +283,7 @@ def batched_cluster_sparse(graph: CSRGraph, seeds, eps=1e-6, alpha=0.01,
     """
     graph = _ops.local_csr(graph)   # any graph-like (GraphHandle ok)
     seeds, B, eps, alpha = _prep_batch(seeds, eps, alpha)
+    check_sparse_eps(eps)
     n = graph.n
     out = dict(conductance=np.full((B, cap_v), np.inf, np.float32),
                best_conductance=np.full(B, np.inf, np.float32),

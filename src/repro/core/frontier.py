@@ -13,6 +13,11 @@ shapes are static, so the same *work-locality* is obtained with:
                         O(log) depth: exactly the paper's §3 primitives.
   * ``pack_unique``   — the new-frontier ``filter``: sort candidates, mask
                         duplicates + failed predicate, prefix-sum compaction.
+  * ``compact_sorted`` — the same ``filter`` over ids that are already
+                        sorted and unique (a SparseVec's support): mask +
+                        prefix-sum compaction, no sort.  The sparse
+                        PR-Nibble round takes its next frontier this way
+                        from ``r``'s own slots and packs no candidates.
 
 Overflow (frontier or edge workspace exceeding capacity) is detected exactly
 and surfaced as a flag; drivers retry at the next power-of-two bucket
@@ -37,7 +42,7 @@ from repro.graphs.csr import CSRGraph
 from . import ops
 
 __all__ = ["Frontier", "EdgeBatch", "singleton", "expand", "pack_unique",
-           "next_pow2", "DEFAULT_CAPS", "scatter_add_dense",
+           "compact_sorted", "next_pow2", "DEFAULT_CAPS", "scatter_add_dense",
            "scatter_set_dense", "one_hot_f32"]
 
 DEFAULT_CAPS = dict(cap_f=1 << 12, cap_e=1 << 16)
@@ -141,6 +146,28 @@ def pack_unique(cands: jnp.ndarray, keep: jnp.ndarray, n: int,
     out = jnp.full((cap_out,), n, dtype=jnp.int32)
     # drop writes beyond capacity; overflow flag reports the truncation
     out = out.at[jnp.where(sel, pos, cap_out)].set(xs, mode="drop")
+    return Frontier(ids=out, count=jnp.minimum(count, cap_out),
+                    overflow=count > cap_out)
+
+
+@jax.named_scope("frontier")
+def compact_sorted(ids: jnp.ndarray, keep: jnp.ndarray, n: int,
+                   cap_out: int, backend: str = "xla") -> Frontier:
+    """Filter already sorted, duplicate-free ids into a fresh frontier.
+
+    ``ids`` is ascending with sentinel ``n`` padding (a SparseVec's
+    ``ids``); ``keep`` is the predicate mask.  Prefix-sum compaction into
+    ``cap_out`` slots: O(C) work, O(log C) depth, no sort.  The output
+    contract is :func:`pack_unique`'s on the same input — ids ascending,
+    sentinel padded, ``count = min(k, cap_out)``, ``overflow = k > cap_out``
+    for ``k`` kept ids.
+    """
+    sel = keep & (ids < n)
+    pos = ops.prefix_sum(sel.astype(jnp.int32), backend=backend) - 1
+    count = jnp.sum(sel).astype(jnp.int32)
+    out = jnp.full((cap_out,), n, dtype=jnp.int32)
+    # drop writes beyond capacity; overflow flag reports the truncation
+    out = out.at[jnp.where(sel, pos, cap_out)].set(ids, mode="drop")
     return Frontier(ids=out, count=jnp.minimum(count, cap_out),
                     overflow=count > cap_out)
 

@@ -13,6 +13,18 @@ the serving engine (serve/cluster_engine.py) can step the *same* round
 function the single-seed driver runs — that sharing is what makes their
 per-seed bit-identity guarantee structural rather than aspirational.
 
+The round takes its next frontier from ``r``'s sorted support, not from
+a pack of the round's candidates (the pushed and the pushed-to vertices):
+for ε > 0 every vertex at or above its threshold ``d(v)·ε`` after a round
+was touched in that round.  A vertex untouched in round t keeps its
+residual from round t−1; had that been above threshold, the vertex would
+have been in round t's frontier, and so touched.  (After round 1 only the
+seed and its neighbours hold residual; an overflow ends the run.)  So a
+filter of ``r``'s ``cap_v`` slots gives the frontier the candidate pack
+gives — same ids, order, count and overflow — with no sort.  The host
+entry points reject ε ≤ 0 (:func:`check_sparse_eps`), where a zero
+residual left by a push would pass the filter.
+
 Shape/dtype contracts (``n`` = graph.n; sentinel id is ``n``):
   * state ``p``, ``r`` — :class:`SparseVec` of capacity ``cap_v``:
     ``ids`` int32[cap_v] sorted/sentinel-padded, ``vals`` f32[cap_v],
@@ -29,15 +41,17 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.graphs.csr import CSRGraph
-from .frontier import Frontier, expand, pack_unique, singleton
+from .frontier import Frontier, compact_sorted, expand, singleton
 from .sparsevec import (SparseVec, sv_empty, sv_from_pairs, sv_lookup,
                         sv_merge_add, sv_update_existing)
 
 __all__ = ["PRNibbleSparseResult", "PRNibbleSparseState", "pr_nibble_sparse",
            "pr_nibble_sparse_fixedcap", "pr_nibble_sparse_init",
-           "pr_nibble_sparse_round", "pr_nibble_sparse_alive"]
+           "pr_nibble_sparse_round", "pr_nibble_sparse_alive",
+           "check_sparse_eps"]
 
 
 class PRNibbleSparseResult(NamedTuple):
@@ -58,6 +72,19 @@ class PRNibbleSparseState(NamedTuple):
     pushes: jnp.ndarray
     edge_work: jnp.ndarray   # int32 — Σ expanded edges (eb.total) per round
     overflow: jnp.ndarray
+
+
+def check_sparse_eps(eps) -> None:
+    """Reject an ε that is not > 0 (NaN included), scalar or per seed.
+
+    The round's next frontier is ``r``'s support filtered by
+    ``r[v] >= d(v)·ε``; with ε ≤ 0 a zero-valued entry left behind by a push
+    would pass it, and the run would never end before ``max_iters``."""
+    e = np.asarray(eps, dtype=np.float64)
+    bad = e[~(e > 0)]
+    if bad.size:
+        raise ValueError(
+            f"sparse PR-Nibble needs eps > 0 (got {float(bad.flat[0])!r})")
 
 
 def pr_nibble_sparse_init(x, n: int, cap_f: int, cap_v: int) -> PRNibbleSparseState:
@@ -116,13 +143,11 @@ def pr_nibble_sparse_round(graph: CSRGraph, s: PRNibbleSparseState, eps, alpha,
     r_new = sv_merge_add(r_new, eb.dst, share[eb.slot], eb.valid, n,
                          backend=backend)
 
+    # next frontier: r_new's own support, filtered (module docstring)
     with jax.named_scope("frontier"):
-        cands = jnp.concatenate([fids, eb.dst])
-        cvalid = jnp.concatenate([fvalid, eb.valid])
-        csafe = jnp.minimum(cands, n - 1)
-        r_cand = sv_lookup(r_new, cands, n)
-        keep = cvalid & (deg[csafe] > 0) & (r_cand >= deg[csafe] * eps)
-        nf = pack_unique(cands, keep, n, f.cap, backend=backend)
+        rdeg = deg[jnp.minimum(r_new.ids, n - 1)]
+        keep = r_new.valid() & (rdeg > 0) & (r_new.vals >= rdeg * eps)
+        nf = compact_sorted(r_new.ids, keep, n, f.cap, backend=backend)
 
     return PRNibbleSparseState(p=p_new, r=r_new, frontier=nf, t=s.t + 1,
                                pushes=s.pushes + f.count,
@@ -164,6 +189,7 @@ def pr_nibble_sparse(graph: CSRGraph, x, eps: float = 1e-7, alpha: float = 0.01,
     serving engine's bucket-promotion ladder, so all three paths dispatch the
     same static shapes and return bit-identical per-seed results.
     """
+    check_sparse_eps(eps)
     while True:
         out = pr_nibble_sparse_fixedcap(graph, x, eps, alpha, optimized,
                                         cap_f, cap_e, cap_v, backend=backend)

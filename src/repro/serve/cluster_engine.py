@@ -111,6 +111,7 @@ from repro.graphs.handle import GraphHandle, as_handle
 from repro.core import ops as core_ops
 from repro.core.batched_dist import dist_lane_kernels
 from repro.core.pr_nibble import MAX_ITERS
+from repro.core.pr_nibble_sparse import check_sparse_eps
 from repro.core.sweep import sweep_cut_dense, sweep_cut_sparse
 from repro.core.batched import (STATUS_EDGES, STATUS_EXCHANGED,
                                 STATUS_FINISHED, STATUS_FRONTIER, STATUS_ITER,
@@ -847,7 +848,9 @@ class LocalClusterEngine:
         """(method, backend, statics, ops_backend, bucket, topo) — ``topo``
         is the shard topology (axis, D) for dist pools, None otherwise, so
         dist pools can never alias local pools (or each other across
-        meshes) in the compile cache, the LRU, or the telemetry labels."""
+        meshes) in the compile cache, the LRU, or the telemetry labels.
+        A request bound for a sparse lane must have ε > 0
+        (:func:`~repro.core.pr_nibble_sparse.check_sparse_eps`)."""
         if req.method == "pr_nibble":
             statics = (req.optimized, req.beta)
         elif req.method == "hk_pr":
@@ -855,6 +858,8 @@ class LocalClusterEngine:
         else:
             raise ValueError(f"unknown method: {req.method!r}")
         backend = self._resolve_backend(req)
+        if backend == "sparse":
+            check_sparse_eps(req.eps)
         topo = ((self.handle.axis, self.handle.num_shards)
                 if backend == "dist" else None)
         return (req.method, backend, statics,

@@ -10,6 +10,7 @@ an explicit pin) demands while still matching the single-seed drivers.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import (pr_nibble_sparse, sweep_cut,
@@ -17,7 +18,13 @@ from repro.core import (pr_nibble_sparse, sweep_cut,
                         batched_pr_nibble_sparse, batched_cluster_sparse,
                         batched_sparse_sweep_cut, sparse_rows_to_dense,
                         sparse_lane_footprint, pick_backend)
-from repro.serve import ClusterRequest, LocalClusterEngine
+from repro.core.frontier import expand, pack_unique
+from repro.core.pr_nibble_sparse import (pr_nibble_sparse_alive,
+                                         pr_nibble_sparse_init,
+                                         pr_nibble_sparse_round)
+from repro.core.sparsevec import sv_lookup
+from repro.graphs import rmat
+from repro.serve import AsyncClusterEngine, ClusterRequest, LocalClusterEngine
 
 # Right-sized workspaces for the small test graphs (see test_batched.py).
 CAPS = dict(cap_f=1 << 10, cap_e=1 << 14, cap_v=1 << 12)
@@ -248,3 +255,108 @@ def test_sparse_lane_footprint_accounting():
     assert fp["total"] == fp["state"] + fp["transient"]
     # the memory-bound claim: state is K-bounded, independent of any n
     assert fp["state"] < 2 * 50_000               # dense lane on randLocal-50k
+
+
+# ------------------------------------------------- (f) next-frontier rule
+
+# Small enough that the frontier, the edge workspace and the value sets each
+# overflow in some case of the tight (α, ε) pair (R-MAT's hubs fill cap_e).
+RULE_CAPS = dict(cap_f=1 << 8, cap_e=1 << 11, cap_v=1 << 10)
+
+
+@pytest.fixture(scope="module")
+def rmat_graph():
+    return rmat(10, 8, seed=2)
+
+
+def _candidate_frontier(graph, s, r_new, eps, cap_e):
+    """The candidate pack the sparse round used to run: the pushed
+    (``fids``) and pushed-to (``eb.dst``) vertices, each tested against
+    the round's ``r_new``, sorted and deduped."""
+    n = graph.n
+    f = s.frontier
+    fvalid = f.valid()
+    fids = jnp.where(fvalid, f.ids, n)
+    eb = expand(graph, f, cap_e)
+    cands = jnp.concatenate([fids, eb.dst])
+    cvalid = jnp.concatenate([fvalid, eb.valid])
+    csafe = jnp.minimum(cands, n - 1)
+    r_cand = sv_lookup(r_new, cands, n)
+    keep = (cvalid & (graph.deg[csafe] > 0)
+            & (r_cand >= graph.deg[csafe] * eps))
+    return pack_unique(cands, keep, n, f.cap)
+
+
+@pytest.mark.parametrize("eps,alpha,overflows",
+                         [(1e-3, 0.1, False), (1e-4, 0.02, True)],
+                         ids=["ample", "tight"])
+@pytest.mark.parametrize("optimized", [True, False])
+@pytest.mark.parametrize("graph_name", ["rmat_graph", "sbm_graph",
+                                        "local_graph"])
+def test_sparse_round_frontier_equals_candidate_pack(request, graph_name,
+                                                     optimized, eps, alpha,
+                                                     overflows):
+    """After every round, the frontier taken from ``r``'s sorted support
+    equals the candidate pack computed from the same pre-round state: ids,
+    count and overflow flag, through the round where a capacity overflows."""
+    graph = request.getfixturevalue(graph_name)
+    cap_e = RULE_CAPS["cap_e"]
+
+    @jax.jit
+    def step(s, e, a):
+        s2 = pr_nibble_sparse_round(graph, s, e, a, optimized, cap_e)
+        return s2, _candidate_frontier(graph, s, s2.r, e, cap_e)
+
+    deg = np.asarray(graph.deg)
+    seeds = np.random.default_rng(0).choice(np.flatnonzero(deg > 0), size=6)
+    rounds = 0
+    overflowed = False
+    for seed in seeds:
+        s = pr_nibble_sparse_init(int(seed), graph.n, RULE_CAPS["cap_f"],
+                                  RULE_CAPS["cap_v"])
+        while bool(pr_nibble_sparse_alive(s)):
+            s, want = step(s, jnp.float32(eps), jnp.float32(alpha))
+            got = s.frontier
+            np.testing.assert_array_equal(np.asarray(got.ids),
+                                          np.asarray(want.ids))
+            assert int(got.count) == int(want.count)
+            assert bool(got.overflow) == bool(want.overflow)
+            overflowed |= bool(s.overflow)
+            rounds += 1
+    assert rounds > len(seeds)
+    assert overflowed == overflows
+
+
+# ------------------------------------------------- (g) eps > 0 on sparse lanes
+
+_SMALL = dict(cap_f=1 << 5, cap_e=1 << 7, cap_v=1 << 6)
+
+
+def _submit_sparse(graph, eps, wrap):
+    eng = LocalClusterEngine(graph, batch_slots=2, backend="sparse",
+                             cap_n=1 << 8, sweep_cap_e=1 << 10, **_SMALL)
+    # a dense lane takes any eps: the rule is the sparse round's
+    eng._pool_key(ClusterRequest(seed=5, eps=eps, backend="dense"), 0)
+    front = AsyncClusterEngine(eng) if wrap else eng
+    front.submit(ClusterRequest(seed=5, eps=eps))
+
+
+_ENTRY_POINTS = {
+    "pr_nibble_sparse": lambda g, e: pr_nibble_sparse(g, 5, e, 0.05,
+                                                      **_SMALL),
+    "batched_pr_nibble_sparse": lambda g, e: batched_pr_nibble_sparse(
+        g, [5, 6], [1e-4, e], 0.05, **_SMALL),
+    "batched_cluster_sparse": lambda g, e: batched_cluster_sparse(
+        g, [5, 6], [e, 1e-4], 0.05, **_SMALL),
+    "engine": lambda g, e: _submit_sparse(g, e, wrap=False),
+    "async_engine": lambda g, e: _submit_sparse(g, e, wrap=True),
+}
+
+
+@pytest.mark.parametrize("eps", [0.0, -1e-5, float("nan")])
+@pytest.mark.parametrize("entry", sorted(_ENTRY_POINTS))
+def test_sparse_pr_nibble_rejects_nonpositive_eps(local_graph, entry, eps):
+    """ε ≤ 0 (or NaN) is refused before any lane runs: with it a zero
+    residual would pass the support filter and the run would not end."""
+    with pytest.raises(ValueError, match="eps > 0"):
+        _ENTRY_POINTS[entry](local_graph, eps)

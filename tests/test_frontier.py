@@ -8,7 +8,8 @@ pytest.importorskip("hypothesis", reason="property suite needs hypothesis "
                     "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
-from repro.core.frontier import Frontier, expand, pack_unique, singleton
+from repro.core.frontier import (Frontier, compact_sorted, expand,
+                                 pack_unique, singleton)
 from repro.core.sparsevec import (sv_empty, sv_from_pairs, sv_lookup,
                                   sv_merge_add, sv_update_existing)
 from repro.graphs import rand_local
@@ -53,6 +54,31 @@ def test_pack_unique_overflow_flag():
     f = pack_unique(cands, jnp.ones(100, bool), n=1000, cap_out=16)
     assert bool(f.overflow)
     assert int(f.count) == 16
+
+
+@pytest.mark.parametrize("room", [-5, 0, 7], ids=["below", "at", "above"])
+def test_compact_sorted_equals_pack_unique(room):
+    """On sorted, duplicate-free, sentinel-padded ids (a SparseVec's
+    support) the sort-free compaction returns what ``pack_unique`` returns
+    — ids, count, overflow and the truncation — for ``cap_out`` below, at
+    and above the kept count, and the same bits on both op backends."""
+    n, cap = 1000, 96
+    rng = np.random.default_rng(11)
+    live = np.sort(rng.choice(n, size=80, replace=False)).astype(np.int32)
+    ids = np.full(cap, n, np.int32)
+    ids[: live.size] = live
+    keep = rng.random(cap) < 0.6
+    keep[live.size + 3] = True          # a kept sentinel stays out
+    k = int(keep[: live.size].sum())
+    cap_out = k + room
+    ids, keep = jnp.asarray(ids), jnp.asarray(keep)
+    want = pack_unique(ids, keep, n, cap_out)
+    for backend in ("xla", "pallas"):
+        got = compact_sorted(ids, keep, n, cap_out, backend=backend)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(want.count) == min(k, cap_out)
+    assert bool(want.overflow) == (room < 0)
 
 
 @settings(max_examples=25, deadline=None)
