@@ -53,8 +53,7 @@ def main(argv=None) -> int:
     from bench import harness, loadgen
     from bench.reference import HostGraph
     cell = harness.resolve(harness.load_spec(), args.workload)
-    host = HostGraph.of(harness.build_graph(cell.config,
-                                            harness.graph_seed(cell)))
+    host = HostGraph.of(harness.build_graph(cell))
     for seed in (int(s) for s in args.seeds.split(",")):
         stream = loadgen.make_stream(cell.traffic, host.deg, seed)
         verdict = control_verdict(host, cell.traffic,

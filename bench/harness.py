@@ -4,7 +4,8 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by the names in ``BENCHMARK.json``:
 
 * ``configs[].file`` — the configuration, whose ``generator`` names
-  ``bench/graphs/<generator>.py``;
+  ``bench/graphs/<generator>.py``, whose ``build(key, config, devices)``
+  builds the graph over the cell's chips;
 * ``workloads[].traffic`` — ``bench/traffic/<traffic>.json``, read by
   ``bench/loadgen.py``;
 * ``per_layer[].name`` — ``bench/metrics/<name>.py``, whose ``read(run)``
@@ -22,8 +23,6 @@ import threading
 import time
 from pathlib import Path
 from typing import Optional
-
-import numpy as np
 
 import jax
 
@@ -96,14 +95,12 @@ def graph_seed(cell: Cell) -> int:
     return cell.traffic["population_seed"]
 
 
-def build_graph(config: dict, seed: int):
-    """The configuration's graph, generated and built on the device."""
-    from bench.graphs import csr
-    gen = importlib.import_module(f"bench.graphs.{config['generator']}")
-    src, dst, n = gen.generate(graph_key(seed), config)
-    graph = csr.build_csr(src, dst, n, config["undirected_edges"])
-    jax.block_until_ready(graph.indices)
-    return graph
+def build_graph(cell: Cell):
+    """The cell's graph, generated and built over the cell's chips by its
+    generator's ``build``: a ``GraphHandle``."""
+    gen = importlib.import_module(f"bench.graphs.{cell.config['generator']}")
+    return gen.build(graph_key(graph_seed(cell)), cell.config,
+                     jax.devices()[:cell.chips])
 
 
 # ----------------------------------------------------------------- driving
@@ -238,7 +235,7 @@ def serve(cell: Cell, graph, seed: int, seconds: float, trace: bool,
           t_start: float) -> Run:
     """Set up the engine on ``graph``, warm it, drive the window, and wait
     up to ``GRACE_S`` past its close for every request it sent."""
-    stream = loadgen.make_stream(cell.traffic, np.asarray(graph.deg), seed)
+    stream = loadgen.make_stream(cell.traffic, graph.degrees(), seed)
     tracer = Tracer(device_annotations=True) if trace else None
     eng = LocalClusterEngine(graph)
     srv = AsyncClusterEngine(eng, tracer=tracer)
@@ -292,7 +289,7 @@ def device_info(trace: Optional[xplane.Reduced]) -> dict:
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_start: float) -> dict:
     """One whole run; returns the result object the last line prints."""
-    graph = build_graph(cell.config, graph_seed(cell))
+    graph = build_graph(cell)
     run = serve(cell, graph, seed, seconds, trace, t_start)
     device = device_info(run.profile)
     host = HostGraph.of(graph)

@@ -33,11 +33,13 @@ class HostGraph(NamedTuple):
     m: int
 
     @classmethod
-    def of(cls, graph) -> "HostGraph":
-        """Host copy of a CSR graph's arrays."""
-        indptr = np.asarray(graph.indptr).astype(np.int64)
-        return cls(indptr, np.asarray(graph.indices), np.diff(indptr),
-                   int(graph.n), int(graph.m))
+    def of(cls, handle) -> "HostGraph":
+        """Host copy of a graph handle's arrays, read one shard at a time
+        where the graph is sharded."""
+        from bench.graphs.shards import host_csr
+        indptr, indices = host_csr(handle)
+        return cls(indptr, indices, np.diff(indptr), int(handle.n),
+                   int(handle.m))
 
 
 class Answer(NamedTuple):

@@ -3,14 +3,15 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Builds the cell's graph on the chip from ``--seed``, serves the cell's
-traffic through ``AsyncClusterEngine`` over a ``LocalClusterEngine`` with
-its defaults, measures for ``--seconds``, checks the answers against the
-plain reference (``bench/check.py``) and prints one JSON object.  With
-``--trace 0`` its metrics are the cell's end-to-end metrics, with
-``--trace 1`` its per-layer metrics, read from the program's spans and a
-profiler trace of a few seconds of the window.  Exits 2 before building
-anything when JAX finds no TPU or fewer chips than the cell asks for.
+Builds the cell's graph over the cell's chips (``bench/graphs/``), serves
+the cell's traffic through ``AsyncClusterEngine`` over a
+``LocalClusterEngine`` with its defaults, measures for ``--seconds``,
+checks the answers against the plain reference (``bench/check.py``) and
+prints one JSON object.  With ``--trace 0`` its metrics are the cell's
+end-to-end metrics, with ``--trace 1`` its per-layer metrics, read from the
+program's spans and a profiler trace of a few seconds of the window.  Exits
+2 before building anything when JAX finds no TPU or fewer chips than the
+cell asks for.
 """
 import time
 

@@ -3,6 +3,8 @@ out as not correct by the run's own judge, and the reference passes
 against itself."""
 import pytest
 
+import jax
+
 from bench import harness, loadgen
 from bench.control import control_verdict
 from bench.graphs import csr, rmat
@@ -14,9 +16,10 @@ TRAFFIC = harness.resolve(harness.load_spec(), "rmat24.lookups").traffic
 @pytest.fixture(scope="module")
 def graph():
     cfg = dict(scale=12, edge_factor=16, a=0.57, b=0.19, c=0.19)
-    src, dst, n = rmat.generate(harness.graph_key(7), cfg)
-    g = csr.build_csr(src, dst, n, int(csr.count_unique(src, dst, n)))
-    return HostGraph.of(g)
+    key = harness.graph_key(7)
+    src, dst, n = rmat.generate(key, cfg)
+    cfg["undirected_edges"] = int(csr.count_unique(src, dst, n))
+    return HostGraph.of(rmat.build(key, cfg, jax.devices()[:1]))
 
 
 @pytest.mark.parametrize("seed", [7, 2**33 + 1])

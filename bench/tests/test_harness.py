@@ -86,7 +86,8 @@ def test_result_line_has_the_contract_keys(trace):
     if trace:
         assert {"busy_s", "window_s"} <= set(out["device"])
         # no device plane, and so no device operations, on the CPU
-        want -= {"device_idle_pct", "step_device_ms"}
+        want -= {m["name"] for m in SPEC["per_layer"]
+                 if m["source"] == "device_trace"}
         assert want <= set(out["metrics"])
     else:
         assert set(out["metrics"]) == want
